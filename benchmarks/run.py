@@ -37,8 +37,12 @@ def main() -> None:
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                    + " --xla_force_host_platform_device_count=8")
 
+    from repro.launch.runtime import enable_compile_cache
+
     from . import (kd_curves, kernel_bench, paper_tables, pareto,
                    roofline_report, secure_e2e, secure_lm)
+
+    enable_compile_cache()
 
     suites = {
         "table1": paper_tables.table1,

@@ -724,9 +724,8 @@ def make_secure_infer_mesh(model: SecureModel, mesh, *,
             rep = verifier.traced_report()
             return out, {k: v[None] for k, v in rep.items()}
 
-    sm = transport.shard_map_compat(inner, mesh=mesh, in_specs=in_specs,
-                                    out_specs=out_specs,
-                                    **transport.SHARD_MAP_CHECK_KW)
+    sm = jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
 
     def roll(a):
         return jnp.roll(a, -1, axis=0)

@@ -482,7 +482,12 @@ def secure_prefill(lm: SecureLMParams, cache: SecureKVCache, tokens, keys,
 class CompiledDecodeStep:
     """One jitted decode step per padded bucket length, with a trace-time
     counter: serving keeps a dict keyed by bucket and asserts the program
-    compiled exactly once per bucket (pinned in tests)."""
+    compiled exactly once per bucket (pinned in tests).
+
+    A ``step_fn`` that carries a ``cache_sharding`` (the mesh step) gets
+    every incoming cache placed there first, so a host-built initial cache
+    and the step's own output enter the jit with one sharding and the
+    program traces once."""
 
     def __init__(self, lm: SecureLMParams | None = None,
                  customized: bool = True, static_norm: bool = False,
@@ -502,9 +507,12 @@ class CompiledDecodeStep:
         # (the prefill scan) without charging this step's trace budget
         self.raw = step_fn
         self._jit = jax.jit(counted)
+        self.cache_sharding = getattr(step_fn, "cache_sharding", None)
 
     def __call__(self, cache, tok, pos, keys):
         from . import telemetry
+        if self.cache_sharding is not None:
+            cache = jax.device_put(cache, self.cache_sharding)
         if not telemetry.enabled():   # disabled mode: no clock, no span
             return self._jit(cache, tok, pos, keys)
         # the traces counter distinguishes the compile call from steady-
@@ -529,9 +537,11 @@ def make_secure_lm_mesh(lm: SecureLMParams, mesh, customized: bool = True,
     global pair layout ``(6, ...)`` (``out_specs=P(party)`` stacks each
     party's ``(2, ...)`` result, and the next call's ``in_specs=P(party)``
     splits the same rows back), so no re-pairing is needed between steps.
-    Returns ``step(cache, tok, pos, keys) -> (logits, cache)``.
+    Returns ``step(cache, tok, pos, keys) -> (logits, cache)``; its
+    ``cache_sharding`` is the sharding of the cache it returns, where
+    :class:`CompiledDecodeStep` places every incoming cache.
     """
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     assert mesh.shape[party_axis] == 3, mesh
     leaves, treedef = jax.tree_util.tree_flatten(lm)
@@ -547,12 +557,12 @@ def make_secure_lm_mesh(lm: SecureLMParams, mesh, customized: bool = True,
                                             customized, static_norm)
             return logits[None], c2.k, c2.v
 
-    sm = transport.shard_map_compat(
+    sm = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(), P(), P(), (w_spec,) * len(leaves),
                   (w_spec,) * len(leaves), w_spec, w_spec),
         out_specs=(w_spec, w_spec, w_spec),
-        **transport.SHARD_MAP_CHECK_KW)
+        check_vma=False)
 
     def roll(a):
         return jnp.roll(a, -1, axis=0)
@@ -566,6 +576,7 @@ def make_secure_lm_mesh(lm: SecureLMParams, mesh, customized: bool = True,
                         cache.k, cache.v)
         return lg[0], SecureKVCache(ck, cv)
 
+    step.cache_sharding = NamedSharding(mesh, w_spec)
     return step
 
 

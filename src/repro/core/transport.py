@@ -58,7 +58,6 @@ movement primitives and compiles with zero PRF work.
 from __future__ import annotations
 
 import contextlib
-import inspect
 from typing import Callable, Sequence
 
 import jax
@@ -67,20 +66,8 @@ import jax.numpy as jnp
 from . import integrity
 from . import telemetry
 
-try:
-    from jax import shard_map as shard_map_compat
-except ImportError:  # jax<0.7 layout
-    from jax.experimental.shard_map import shard_map as shard_map_compat
-
-# the replication-check kwarg was renamed check_rep -> check_vma
-SHARD_MAP_CHECK_KW = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(shard_map_compat).parameters
-    else {"check_rep": False})
-
 __all__ = ["Transport", "LocalTransport", "MeshTransport", "current",
-           "use_transport", "PARTIES", "shard_map_compat",
-           "SHARD_MAP_CHECK_KW"]
+           "use_transport", "PARTIES"]
 
 PARTIES = 3
 

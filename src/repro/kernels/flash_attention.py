@@ -33,14 +33,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int,
 
     def body(kv_i, carry):
         m_, l_, acc_ = carry
-        # leading block dims indexed with length-1 slices (int indices break
-        # interpret-mode pl.load on older jax); squeeze after the load
-        k = pl.load(k_ref, (slice(0, 1), slice(0, 1),
-                            pl.dslice(kv_i * bk, bk), slice(None))
-                    )[0, 0].astype(jnp.float32)       # (bk, hd)
-        v = pl.load(v_ref, (slice(0, 1), slice(0, 1),
-                            pl.dslice(kv_i * bk, bk), slice(None))
-                    )[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, pl.ds(kv_i * bk, bk), :].astype(jnp.float32)  # (bk, hd)
+        v = v_ref[0, 0, pl.ds(kv_i * bk, bk), :].astype(jnp.float32)
         s = q @ k.T                                    # (bq, bk)
         kv_pos = kv_i * bk + jax.lax.iota(jnp.int32, bk)
         mask = q_pos[:, None] >= kv_pos[None, :]

@@ -54,20 +54,37 @@ import os
 import sys
 import time
 
+from repro.launch.runtime import device_info, enable_compile_cache
+
 
 def build(net: str, use_kernel: bool, weights: str = "shared",
-          binary_linear: str = "auto", deployment=None):
+          binary_linear: str = "auto", deployment=None, params=None):
+    """Compile ``params`` (default: ``init_bnn`` weights from seed 0) into
+    the secure model."""
     import jax
     from repro.core import RING32
     from repro.core.secure_model import compile_secure
     from repro.nn import bnn
 
-    params = bnn.init_bnn(jax.random.PRNGKey(0), net)
+    if params is None:
+        params = bnn.init_bnn(jax.random.PRNGKey(0), net)
     model = compile_secure(params, net, jax.random.PRNGKey(1), RING32,
                            use_kernel_dot=use_kernel, weights=weights,
                            binary_linear=binary_linear,
                            deployment=deployment)
     return model
+
+
+def party_devices() -> list:
+    """The devices a mesh backend lays its parties on, one party per
+    device; exits when there are fewer than three."""
+    import jax
+    devs = jax.devices()
+    if len(devs) < 3:
+        raise SystemExit(
+            f"mesh backend runs one party per device and needs 3 devices; "
+            f"found {len(devs)} {devs[0].platform} device(s)")
+    return devs
 
 
 def make_runner(model, backend: str, batch: int, party_axis: str = "party",
@@ -109,14 +126,11 @@ def make_runner(model, backend: str, batch: int, party_axis: str = "party",
             return out
         return run, None
 
-    n_dev = len(jax.devices())
-    if n_dev < 3:
-        raise SystemExit(f"mesh backend needs >= 3 devices, have {n_dev} "
-                         "(set XLA_FLAGS=--xla_force_host_platform_device_count=8)")
+    devices = party_devices()
     # the digest report layout is per-party: verified mesh runs party-only
     data = 1 if v is not None else \
-        max(d for d in range(1, n_dev // 3 + 1) if batch % d == 0)
-    devs = np.asarray(jax.devices()[:3 * data])
+        max(d for d in range(1, len(devices) // 3 + 1) if batch % d == 0)
+    devs = np.asarray(devices[:3 * data])
     if data > 1:
         mesh = jax.sharding.Mesh(devs.reshape(3, data), (party_axis, "data"))
         fn = make_secure_infer_mesh(model, mesh, batch_axis="data")
@@ -167,12 +181,8 @@ def make_tape_runner(model, spec, backend: str, party_axis: str = "party",
             v.check(rep)
             return out
         return run, (lambda x_stack, slabs: (x_stack, slabs)), None
-    n_dev = len(jax.devices())
-    if n_dev < 3:
-        raise SystemExit(f"mesh backend needs >= 3 devices, have {n_dev} "
-                         "(set XLA_FLAGS=--xla_force_host_platform_device_count=8)")
     # tape material is traced at the global batch: party-only mesh
-    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:3]), (party_axis,))
+    mesh = jax.sharding.Mesh(np.asarray(party_devices()[:3]), (party_axis,))
     fn = make_secure_infer_mesh(model, mesh, tape_spec=spec, verifier=v)
     jitted = jax.jit(fn)
     if v is None:
@@ -304,12 +314,7 @@ def _serve_lm(args, ap, tracer=None, reg=None):
     # one compiled step per padded bucket length
     slots = 3
     if args.backend == "mesh":
-        n_dev = len(jax.devices())
-        if n_dev < 3:
-            raise SystemExit(
-                f"mesh backend needs >= 3 devices, have {n_dev} (set "
-                "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
-        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:3]), ("party",))
+        mesh = jax.sharding.Mesh(np.asarray(party_devices()[:3]), ("party",))
         print(f"[serve_secure] mesh axes "
               f"{dict(zip(mesh.axis_names, mesh.devices.shape))}")
         mesh_step = make_secure_lm_mesh(lm, mesh, customized, static_norm)
@@ -370,7 +375,8 @@ def _serve_lm(args, ap, tracer=None, reg=None):
              "prompt": prompt_len, "gen": gen, "tok_per_s": tps,
              "comm_kb_per_token": led.nbytes / 1e3, "rounds_per_token":
              led.rounds, "predicted_rounds": pred.rounds,
-             "traces": step.traces, "tokens": toks}
+             "traces": step.traces, "tokens": toks,
+             "device": device_info()}
 
     emit_obs(args, tracer, reg, led, online_s=dt,
              queries=args.queries * gen, unit="token")
@@ -448,7 +454,7 @@ def emit_obs(args, tracer, reg, led, predicted=None, model=None,
     return rep
 
 
-def main():
+def main(argv=None):
     # only the CLI mutates the env (importing this module must not); the
     # flag works only before jax initializes
     if "jax" not in sys.modules:
@@ -536,8 +542,12 @@ def main():
     lm.add_argument("--quick", action="store_true",
                     help="small static-norm preset + token-parity check "
                          "against the fp32 oracle (the CI smoke)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    enable_compile_cache()
+    dev = device_info()
+    print(f"[serve_secure] device: {dev['platform']} {dev['kind']} "
+          f"x{dev['count']}")
     if args.model == "lm":
         if args.quick and args.queries == 4:
             args.queries = 1
@@ -638,7 +648,7 @@ def _serve_bnn(args, ap, tracer=None, reg=None):
              "verify": args.verify, "deployment": args.deployment,
              "comm_mb_per_query": led.megabytes, "rounds": led.rounds,
              "predicted_rounds": pred.rounds,
-             "predicted_bytes": pred.nbytes}
+             "predicted_bytes": pred.nbytes, "device": device_info()}
 
     try:
         if args.offline == "pool":

@@ -6,24 +6,11 @@ cross-shard traffic lowers to all-to-all style collectives under pjit.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 
 from ..launch.context import shard_hint
 from .layers import COMPUTE_DTYPE, act_fn, dense_init
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # jax<0.7 layout
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma
-_SHARD_MAP_CHECK_KW = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else {"check_rep": False})
 
 # Dispatch position computation:
 #  "cumsum": one-hot cumsum — O(T·K·E) int32 intermediate (baseline; this is
@@ -180,11 +167,11 @@ def _moe_ffn_shardmap(p, x, *, top_k: int, act: str, gated: bool,
 
     x_spec = P(batch_ax, seq_ax, None)
     w_spec = P("model", None, None)
-    body_sm = _shard_map(
+    body_sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec,
                   w_spec if gated else P(), w_spec),
-        out_specs=x_spec, **_SHARD_MAP_CHECK_KW)
+        out_specs=x_spec, check_vma=False)
     return body_sm(x, p["router"], p["w_up"],
                    p["w_gate"] if gated else jnp.zeros((), COMPUTE_DTYPE),
                    p["w_down"])

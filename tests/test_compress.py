@@ -11,11 +11,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-import inspect
-try:
-    from jax import shard_map as shard_map_fn
-except ImportError:
-    from jax.experimental.shard_map import shard_map as shard_map_fn
 
 from repro.launch import mesh as mesh_lib
 from repro.optim.compress import int8_psum
@@ -26,12 +21,8 @@ g = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 32), jnp.float32)
 def body(gl):
     return int8_psum(gl[0], "pod")
 
-# the replication-check kwarg was renamed check_rep -> check_vma
-_check = ({"check_vma": False}
-          if "check_vma" in inspect.signature(shard_map_fn).parameters
-          else {"check_rep": False})
-f = shard_map_fn(body, mesh=mesh, in_specs=P("pod", None, None),
-                 out_specs=P(None, None), **_check)
+f = jax.shard_map(body, mesh=mesh, in_specs=P("pod", None, None),
+                  out_specs=P(None, None), check_vma=False)
 got = np.asarray(jax.jit(f)(g))
 want = np.asarray(g.sum(0))
 err = np.abs(got - want).max()

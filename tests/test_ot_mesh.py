@@ -52,10 +52,10 @@ roll = lambda a: jnp.roll(a, -1, axis=0)
 for sender, receiver, helper in ROLES:
     # the plain choice bit for this OT is the xor of all three shares,
     # but the protocol consumes only the slot the sender is missing
-    sm = transport.shard_map_compat(
+    sm = jax.shard_map(
         make_inner(sender, receiver, helper), mesh=mesh,
         in_specs=(P(), P(), P(), P("party"), P("party")),
-        out_specs=P("party"), **transport.SHARD_MAP_CHECK_KW)
+        out_specs=P("party"), check_vma=False)
     args = (keys, jnp.asarray(m0), jnp.asarray(m1), cb.shares,
             roll(cb.shares))
 

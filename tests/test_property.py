@@ -111,7 +111,7 @@ ring_widths = st.sampled_from([RING32, RING64])
 
 def _ring_ctx(ring):
     """RING64 needs 64-bit lanes; scope x64 so the suite stays 32-bit."""
-    return jax.experimental.enable_x64() if ring.bits == 64 else nullcontext()
+    return jax.enable_x64(True) if ring.bits == 64 else nullcontext()
 
 
 def _bound_bits(ring):

@@ -229,10 +229,10 @@ for customized, static_norm in ((True, False), (False, True)):
                                static_norm=static_norm)
             return out.shares
 
-    sm = transport.shard_map_compat(
+    sm = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(), w, w, (w,) * len(leaves), (w,) * len(leaves)),
-        out_specs=w, **transport.SHARD_MAP_CHECK_KW)
+        out_specs=w, check_vma=False)
     glob = np.asarray(jax.jit(sm)(
         keys, xs.shares, roll(xs.shares), tuple(leaves),
         tuple(roll(a) for a in leaves)))

@@ -1,0 +1,117 @@
+"""Compile-only checks of the four RSS kernel families for a TPU v5e chip.
+
+Each test compiles one kernel dispatcher (limb decomposition, padding and
+the Pallas launch) with ``interpret=False`` at a launch shape of secure
+CifarNet2 served at batch 32 — the tuples ``cost_model.model_cost(model,
+(32, 32, 32, 3)).kernel_requests()`` lists — for a described, not attached,
+v5e chip.  The compiled program must hold the Mosaic kernel
+(``tpu_custom_call``) and fit one chip's HBM.  Nothing runs, so these say
+nothing about results or times.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.bin_rss_matmul import (GroupedWeightLimbs,
+                                          PublicGroupedLimbs,
+                                          PublicWeightLimbs,
+                                          bin_grouped_matmul_parts,
+                                          bin_rss_matmul_parts,
+                                          grouped_rss_matmul_parts)
+from repro.kernels.rss_matmul import WeightLimbs, rss_matmul_parts
+
+V5E_HBM_BYTES = 16 * 10**9
+KERNEL_CALL = 'custom_call_target="tpu_custom_call"'
+S = 3          # party slots of the local backend
+L_PUBLIC = 2   # weight limbs of CifarNet2's public fixed-point weights
+
+
+def _tile(n):
+    return -(-n // 128) * 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e:2x2 host, with the persistent
+    compilation cache off: a compile for a described chip cannot be read
+    back from it."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # libtpu logs nowhere
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        cache_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+        compilation_cache.reset_cache()
+
+
+def _compile_and_check(fn, args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert KERNEL_CALL in compiled.as_text()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert 0 < total < V5E_HBM_BYTES, total
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# every distinct launch shape of the 18 per weight mode
+# (M, K, N): the pointwise convolutions of the three blocks, classifier head
+DENSE = [(32768, 16, 16), (8192, 16, 32), (8192, 32, 32), (2048, 32, 48),
+         (2048, 48, 48), (32, 768, 10)]
+# (M, C): the depthwise convolutions (K = 3x3 taps, N = 1)
+GROUPED = [(32768, 3), (32768, 16), (8192, 16), (8192, 32), (2048, 32),
+           (2048, 48)]
+
+
+@pytest.mark.parametrize("m,k,n", DENSE)
+def test_rss_matmul_compiles(one_chip, m, k, n):
+    u32 = [_spec((S, k, n), jnp.uint32, one_chip)] * 2
+    i8 = [_spec((S, 4, _tile(k), _tile(n)), jnp.int8, one_chip)] * 2
+    _compile_and_check(
+        lambda x, *w: rss_matmul_parts(x, WeightLimbs(*w), interpret=False),
+        [_spec((S, m, k), jnp.uint32, one_chip), *u32, *i8])
+
+
+@pytest.mark.parametrize("m,k,n", DENSE)
+def test_bin_rss_matmul_compiles(one_chip, m, k, n):
+    _compile_and_check(
+        lambda x, w, wl: bin_rss_matmul_parts(
+            x, PublicWeightLimbs(w, wl, L_PUBLIC), interpret=False),
+        [_spec((S, m, k), jnp.uint32, one_chip),
+         _spec((k, n), jnp.uint32, one_chip),
+         _spec((L_PUBLIC, _tile(k), _tile(n)), jnp.int8, one_chip)])
+
+
+@pytest.mark.parametrize("m,c", GROUPED)
+def test_grouped_rss_matmul_compiles(one_chip, m, c):
+    k, n = 9, 1
+    u32 = [_spec((S, c, k, n), jnp.uint32, one_chip)] * 2
+    i8 = [_spec((S, 4, c, k, n), jnp.int8, one_chip)] * 2
+    _compile_and_check(
+        lambda x, *w: grouped_rss_matmul_parts(x, GroupedWeightLimbs(*w),
+                                               interpret=False),
+        [_spec((S, c, m, k), jnp.uint32, one_chip), *u32, *i8])
+
+
+@pytest.mark.parametrize("m,c", GROUPED)
+def test_bin_grouped_matmul_compiles(one_chip, m, c):
+    k, n = 9, 1
+    _compile_and_check(
+        lambda x, w, wl: bin_grouped_matmul_parts(
+            x, PublicGroupedLimbs(w, wl, L_PUBLIC), interpret=False),
+        [_spec((S, c, m, k), jnp.uint32, one_chip),
+         _spec((c, k, n), jnp.uint32, one_chip),
+         _spec((L_PUBLIC, c, k, n), jnp.int8, one_chip)])

@@ -132,10 +132,10 @@ args = (keys, x.shares, roll(x.shares), w1.shares, roll(w1.shares),
 
 def check(mesh, x_spec, label, data=1):
     w_spec = P("party")
-    sm = transport.shard_map_compat(
+    sm = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(), x_spec, x_spec) + (w_spec,) * 4,
-        out_specs=x_spec, **transport.SHARD_MAP_CHECK_KW)
+        out_specs=x_spec, check_vma=False)
 
     with comm.track() as led:
         jax.eval_shape(sm, *args)
@@ -201,10 +201,10 @@ def inner_bin(keys, xo, xn, w1o, w1n):
 
 mesh_p = jax.sharding.Mesh(np.asarray(jax.devices()[:3]), ("party",))
 args_b = (keys, xb.shares, roll(xb.shares), w1.shares, roll(w1.shares))
-smb = transport.shard_map_compat(
+smb = jax.shard_map(
     inner_bin, mesh=mesh_p,
     in_specs=(P(), P("party"), P("party"), P("party"), P("party")),
-    out_specs=P("party"), **transport.SHARD_MAP_CHECK_KW)
+    out_specs=P("party"), check_vma=False)
 with comm.track() as led_b:
     jax.eval_shape(smb, *args_b)
 # post-Sign shared layer: ONE reshare round, 3 elements/slot; the public
@@ -228,9 +228,9 @@ def inner_pub(keys, xo, xn):
         return t.own_view(h.shares)
 
 
-smp = transport.shard_map_compat(
+smp = jax.shard_map(
     inner_pub, mesh=mesh_p, in_specs=(P(), P("party"), P("party")),
-    out_specs=P("party"), **transport.SHARD_MAP_CHECK_KW)
+    out_specs=P("party"), check_vma=False)
 with comm.track() as led_p:
     jax.eval_shape(smp, keys, xb.shares, roll(xb.shares))
 assert led_p.nbytes == 0 and led_p.rounds == 0, led_p.summary()
