@@ -21,7 +21,7 @@ import jax
 __all__ = [
     "NetworkModel", "LAN", "WAN", "CommLedger", "track", "record",
     "estimate_cost", "round_barrier", "add_listener", "remove_listener",
-    "listening",
+    "listening", "scope",
 ]
 
 
@@ -183,6 +183,22 @@ def round_barrier(tag: str, rounds: int):
         outer.add(tag, rounds, inner.nbytes)
         if inner.pre_nbytes or inner.pre_rounds:
             outer.add(tag, inner.pre_rounds, inner.pre_nbytes, preprocess=True)
+
+
+@contextlib.contextmanager
+def scope(tag: str):
+    """``jax.named_scope(tag)``, yielding ``tag``: every device op traced
+    inside carries ``tag`` in its HLO ``op_name``, so a profiler trace
+    joins the ledger under the same names.  The executor opens one per
+    ledger head (``l3``, ``sign4``, ``output``) and one per protocol call
+    inside it, whose tag is written once::
+
+        with comm.scope(f"sign{idx}.msb") as tag:
+            msb = msb_extract(h, parties, tag=tag)
+
+    Scopes change only HLO metadata, never the computation."""
+    with jax.named_scope(tag):
+        yield tag
 
 
 def estimate_cost(fn: Callable, *args, **kwargs) -> CommLedger:

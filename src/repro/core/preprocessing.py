@@ -322,10 +322,16 @@ class MaterialTape:
     n_queries: int
 
     def query_slice(self, q: int) -> dict:
-        """The per-query slab dict slot ``q`` (device slicing, async)."""
-        return {k: (v[:, q] if self.spec.slabs[k].layout != REPLICATED
-                    else v[q])
-                for k, v in self.slabs.items()}
+        """The per-query slab dict slot ``q`` (device slicing, async: each
+        slab is cut by small programs of its own, and
+        ``tape_slice_dispatches_total`` counts the slabs)."""
+        with telemetry.span("tape_slice", cat="offline"):
+            sl = {k: (v[:, q] if self.spec.slabs[k].layout != REPLICATED
+                      else v[q])
+                  for k, v in self.slabs.items()}
+        telemetry.inc("tape_slices_total")
+        telemetry.inc("tape_slice_dispatches_total", len(sl))
+        return sl
 
     @property
     def nbytes(self) -> int:
@@ -544,46 +550,49 @@ class TapePool:
 
     def take(self) -> dict:
         """The next per-query slab slice, dispatching the next refill as
-        a buffer drains.  Warns on backpressure, raises
-        :class:`PoolExhaustedError` when the budget is spent."""
-        if self._bufs and self._bufs[0][1] >= self.depth:
-            self._bufs.pop(0)       # drained: swap + prefetch the next
-            if self.prefetch:
+        a buffer drains (all of it the ``tape_take`` span).  Warns on
+        backpressure, raises :class:`PoolExhaustedError` when the budget
+        is spent."""
+        with telemetry.span("tape_take", cat="offline"):
+            if self._bufs and self._bufs[0][1] >= self.depth:
+                self._bufs.pop(0)       # drained: swap + prefetch the next
+                if self.prefetch:
+                    self._prefetch()
+            if not self._bufs:
+                if not self._want_more():
+                    raise PoolExhaustedError(
+                        f"material pool exhausted after {self.taken} "
+                        f"slices: offline budget spent ({self.generated} "
+                        f"buffers x "
+                        f"depth {self.depth}"
+                        + (f", demand {self.demand}" if self.demand else "")
+                        + ") — raise --pool-depth or the buffer budget")
+                # backpressure: budget remains but no buffer is ready — the
+                # online phase blocks on a synchronous refill
+                warnings.warn(
+                    "tape pool underrun: online phase blocked on a "
+                    "synchronous refill (offline plant is falling behind)",
+                    RuntimeWarning, stacklevel=2)
+                telemetry.inc("pool_backpressure_total")
                 self._prefetch()
-        if not self._bufs:
-            if not self._want_more():
-                raise PoolExhaustedError(
-                    f"material pool exhausted after {self.taken} slices: "
-                    f"offline budget spent ({self.generated} buffers x "
-                    f"depth {self.depth}"
-                    + (f", demand {self.demand}" if self.demand else "")
-                    + ") — raise --pool-depth or the buffer budget")
-            # backpressure: budget remains but no buffer is ready — the
-            # online phase blocks on a synchronous refill
-            warnings.warn(
-                "tape pool underrun: online phase blocked on a "
-                "synchronous refill (offline plant is falling behind)",
-                RuntimeWarning, stacklevel=2)
-            telemetry.inc("pool_backpressure_total")
-            self._prefetch()
-        if self.demand is not None and not self._warned_dry \
-                and self.demand - self.taken > self.supply \
-                and not self._want_more():
-            self._warned_dry = True
-            warnings.warn(
-                f"tape pool nearly exhausted: {self.supply} slices left "
-                f"for {self.demand - self.taken} demanded — later queries "
-                f"will abort with PoolExhaustedError",
-                RuntimeWarning, stacklevel=2)
-        tape, slot = self._bufs[0]
-        self._bufs[0][1] += 1
-        self.taken += 1
-        if telemetry.enabled():
-            telemetry.gauge("pool_supply", self.supply)
-        sl = tape.query_slice(slot)
-        if self.verify:
-            verify_tape_slice(self.spec, sl)
-        return sl
+            if self.demand is not None and not self._warned_dry \
+                    and self.demand - self.taken > self.supply \
+                    and not self._want_more():
+                self._warned_dry = True
+                warnings.warn(
+                    f"tape pool nearly exhausted: {self.supply} slices left "
+                    f"for {self.demand - self.taken} demanded — later "
+                    f"queries will abort with PoolExhaustedError",
+                    RuntimeWarning, stacklevel=2)
+            tape, slot = self._bufs[0]
+            self._bufs[0][1] += 1
+            self.taken += 1
+            if telemetry.enabled():
+                telemetry.gauge("pool_supply", self.supply)
+            sl = tape.query_slice(slot)
+            if self.verify:
+                verify_tape_slice(self.spec, sl)
+            return sl
 
 
 # ---------------------------------------------------------------------------

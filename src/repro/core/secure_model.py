@@ -308,6 +308,10 @@ def _weight_limbs_for(w: RSS, kind: str, part_idx: int):
         w.shares.reshape(3, kh * kw, cout, 1).transpose(0, 2, 1, 3))
 
 
+# the tag of each linear kind's product (``pw``: a sepconv's pointwise half)
+_LIN_TAG = {"fc": "fc", "conv": "conv", "pw": "pwconv"}
+
+
 def _infer_linear_shared(h: RSS, op: dict, parties: Parties, idx: int,
                          ring: RingSpec, binary_in: bool,
                          binary_engine: bool) -> RSS:
@@ -331,16 +335,18 @@ def _infer_linear_shared(h: RSS, op: dict, parties: Parties, idx: int,
         # no truncation); otherwise the arith route pays the dwtrunc.
         cin = int(h.shape[-1])
         if binary_in and binary_engine:
-            h = bin_conv2d(h, op["w"][0], parties, stride=op["stride"],
-                           padding=op["pad"], groups=cin,
-                           tag=f"l{idx}.dwconv.bin", w_limbs=wlimbs[0],
-                           kcfg=kcfgs[0])
+            with comm.scope(f"l{idx}.dwconv.bin") as tag:
+                h = bin_conv2d(h, op["w"][0], parties, stride=op["stride"],
+                               padding=op["pad"], groups=cin, tag=tag,
+                               w_limbs=wlimbs[0], kcfg=kcfgs[0])
         else:
-            h = conv2d(h, op["w"][0], parties, stride=op["stride"],
-                       padding=op["pad"], groups=cin, tag=f"l{idx}.dwconv",
-                       w_limbs=wlimbs[0], kcfg=kcfgs[0])
+            with comm.scope(f"l{idx}.dwconv") as tag:
+                h = conv2d(h, op["w"][0], parties, stride=op["stride"],
+                           padding=op["pad"], groups=cin, tag=tag,
+                           w_limbs=wlimbs[0], kcfg=kcfgs[0])
             if not binary_in:
-                h = truncate(h, parties, tag=f"l{idx}.dwtrunc")
+                with comm.scope(f"l{idx}.dwtrunc") as tag:
+                    h = truncate(h, parties, tag=tag)
         at_2f = True
         lin, w_rss, wl, kc = "pw", op["w"][1], wlimbs[1], kcfgs[1]
     else:
@@ -351,12 +357,13 @@ def _infer_linear_shared(h: RSS, op: dict, parties: Parties, idx: int,
         # the single reshare round — 3 ring elements per output slot
         bias = tp.own_view(op["b"].shares).reshape(
             (tp.parts_slots,) + (1,) * (h.ndim - 1) + (-1,))
-        if lin == "fc":
-            return bin_matmul(h, w_rss, parties, tag=f"l{idx}.fc.bin",
-                              w_limbs=wl, bias_parts=bias, kcfg=kc)
-        return bin_conv2d(h, w_rss, parties, stride=op["stride"],
-                          padding=op["pad"], tag=f"l{idx}.conv.bin",
-                          w_limbs=wl, bias_parts=bias, kcfg=kc)
+        with comm.scope(f"l{idx}.{lin}.bin") as tag:
+            if lin == "fc":
+                return bin_matmul(h, w_rss, parties, tag=tag, w_limbs=wl,
+                                  bias_parts=bias, kcfg=kc)
+            return bin_conv2d(h, w_rss, parties, stride=op["stride"],
+                              padding=op["pad"], tag=tag, w_limbs=wl,
+                              bias_parts=bias, kcfg=kc)
     if at_2f and fused_rounds():
         # beyond-paper default: product + bias + Π_trunc in the one
         # reshare round (matmul_truncate / conv2d_truncate) — the
@@ -364,24 +371,25 @@ def _infer_linear_shared(h: RSS, op: dict, parties: Parties, idx: int,
         bias = tp.own_view(op["b"].shares).reshape(
             (tp.parts_slots,) + (1,) * (h.ndim - 1) + (-1,))
         bias = bias * jnp.asarray(ring.scale, ring.dtype)
+        with comm.scope(f"l{idx}.{_LIN_TAG[lin]}") as tag:
+            if lin == "fc":
+                return matmul_truncate(h, w_rss, parties, tag=tag,
+                                       w_limbs=wl, bias_parts=bias, kcfg=kc)
+            if lin == "conv":
+                return conv2d_truncate(h, w_rss, parties,
+                                       stride=op["stride"], padding=op["pad"],
+                                       tag=tag, w_limbs=wl, bias_parts=bias,
+                                       kcfg=kc)
+            return conv2d_truncate(h, w_rss, parties, tag=tag, w_limbs=wl,
+                                   bias_parts=bias, kcfg=kc)
+    with comm.scope(f"l{idx}.{_LIN_TAG[lin]}") as tag:
         if lin == "fc":
-            return matmul_truncate(h, w_rss, parties, tag=f"l{idx}.fc",
-                                   w_limbs=wl, bias_parts=bias, kcfg=kc)
-        if lin == "conv":
-            return conv2d_truncate(h, w_rss, parties, stride=op["stride"],
-                                   padding=op["pad"], tag=f"l{idx}.conv",
-                                   w_limbs=wl, bias_parts=bias, kcfg=kc)
-        return conv2d_truncate(h, w_rss, parties, tag=f"l{idx}.pwconv",
-                               w_limbs=wl, bias_parts=bias, kcfg=kc)
-    if lin == "fc":
-        z = matmul(h, w_rss, parties, tag=f"l{idx}.fc", w_limbs=wl, kcfg=kc)
-    elif lin == "conv":
-        z = conv2d(h, w_rss, parties, stride=op["stride"],
-                   padding=op["pad"], tag=f"l{idx}.conv", w_limbs=wl,
-                   kcfg=kc)
-    else:
-        z = conv2d(h, w_rss, parties, tag=f"l{idx}.pwconv", w_limbs=wl,
-                   kcfg=kc)
+            z = matmul(h, w_rss, parties, tag=tag, w_limbs=wl, kcfg=kc)
+        elif lin == "conv":
+            z = conv2d(h, w_rss, parties, stride=op["stride"],
+                       padding=op["pad"], tag=tag, w_limbs=wl, kcfg=kc)
+        else:
+            z = conv2d(h, w_rss, parties, tag=tag, w_limbs=wl, kcfg=kc)
     # z is a full RSS here, so the bias is added share-wise
     bias = op["b"].shares.reshape(
         (z.shares.shape[0],) + (1,) * (z.ndim - 1) + (-1,))
@@ -389,7 +397,8 @@ def _infer_linear_shared(h: RSS, op: dict, parties: Parties, idx: int,
         bias = bias * jnp.asarray(ring.scale, ring.dtype)
     z = RSS(z.shares + bias, ring)
     if at_2f:
-        z = truncate(z, parties, tag=f"l{idx}.trunc")
+        with comm.scope(f"l{idx}.trunc") as tag:
+            z = truncate(z, parties, tag=tag)
     return z
 
 
@@ -407,27 +416,39 @@ def _infer_linear_public(h: RSS, op: dict, parties: Parties, idx: int,
     kcfgs = op.get("kcfg") or [None] * len(op["pub_w"])
     if kind == "sepconv":
         cin = int(h.shape[-1])
-        h = bin_conv2d(h, op["pub_w"][0], parties, stride=op["stride"],
-                       padding=op["pad"], groups=cin,
-                       tag=f"l{idx}.dwconv.pub", kcfg=kcfgs[0])
+        with comm.scope(f"l{idx}.dwconv.pub") as tag:
+            h = bin_conv2d(h, op["pub_w"][0], parties, stride=op["stride"],
+                           padding=op["pad"], groups=cin, tag=tag,
+                           kcfg=kcfgs[0])
         if not binary_in:
-            h = truncate(h, parties, tag=f"l{idx}.dwtrunc")
+            with comm.scope(f"l{idx}.dwtrunc") as tag:
+                h = truncate(h, parties, tag=tag)
         # pointwise input carries scale f, so the product lands at 2f
-        h = bin_conv2d(h, op["pub_w"][1], parties, tag=f"l{idx}.pwconv.pub",
-                       bias_public=pub_b << lift, kcfg=kcfgs[1])
-        return truncate(h, parties, tag=f"l{idx}.trunc")
+        with comm.scope(f"l{idx}.pwconv.pub") as tag:
+            h = bin_conv2d(h, op["pub_w"][1], parties, tag=tag,
+                           bias_public=pub_b << lift, kcfg=kcfgs[1])
+        with comm.scope(f"l{idx}.trunc") as tag:
+            return truncate(h, parties, tag=tag)
     w = op["pub_w"][0]
     bias = pub_b if binary_in else pub_b << lift
-    if kind == "fc":
-        h = bin_matmul(h, w, parties, tag=f"l{idx}.fc.pub",
-                       bias_public=bias, kcfg=kcfgs[0])
-    else:
-        h = bin_conv2d(h, w, parties, stride=op["stride"],
-                       padding=op["pad"], tag=f"l{idx}.conv.pub",
-                       bias_public=bias, kcfg=kcfgs[0])
+    with comm.scope(f"l{idx}.{kind}.pub") as tag:
+        if kind == "fc":
+            h = bin_matmul(h, w, parties, tag=tag, bias_public=bias,
+                           kcfg=kcfgs[0])
+        else:
+            h = bin_conv2d(h, w, parties, stride=op["stride"],
+                           padding=op["pad"], tag=tag, bias_public=bias,
+                           kcfg=kcfgs[0])
     if not binary_in:
-        h = truncate(h, parties, tag=f"l{idx}.trunc")
+        with comm.scope(f"l{idx}.trunc") as tag:
+            h = truncate(h, parties, tag=tag)
     return h
+
+
+# ledger head of each executor op kind: the head of every tag the op
+# records and the name of its jax.named_scope (``l3``, ``sign4``, ``mp5``)
+_HEADS = {"conv": "l", "sepconv": "l", "fc": "l", "sign": "sign",
+          "relu": "relu", "affine": "aff", "maxpool": "mp"}
 
 
 def secure_infer(model: SecureModel, x_shares: RSS, parties: Parties,
@@ -454,93 +475,101 @@ def secure_infer(model: SecureModel, x_shares: RSS, parties: Parties,
 
     for idx, op in enumerate(model.ops):
         kind = op["op"]
-        if kind in ("conv", "sepconv", "fc"):
-            # product scale: input(±1 int: 0 | fixed: f) + W(f) => f or 2f
-            binary_in = op.get("binary_in", False)
-            if model.binary_linear == "off" and binary_in:
-                # binarization-unaware ablation: lift ±1 to scale f and pay
-                # the full arithmetic opening
-                h = h.mul_public_int(jnp.asarray(ring.scale, ring.dtype))
-                binary_in = False
-            if model.weights == "public":
-                h = _infer_linear_public(h, op, parties, idx, ring,
-                                         binary_in)
-            else:
-                # the compile-time solver may pin the engine choice per op
-                # (cost_model.annotate_model); absent that, the model-wide
-                # routing mode decides
-                h = _infer_linear_shared(
-                    h, op, parties, idx, ring, binary_in,
-                    binary_engine=op.get(
-                        "engine", model.binary_linear == "auto"))
-            prev_sign = False
-            pending_sign_threshold = (op.get("sign_threshold")
-                                      if model.weights == "shared"
-                                      else op.get("pub_thresh"))
-        elif kind == "sign":
-            if pending_sign_threshold is not None:
-                t = pending_sign_threshold
-                if isinstance(t, RSS):
-                    h = RSS(h.shares + t.shares.reshape(
-                        (h.shares.shape[0],) + (1,) * (h.ndim - 1) + (-1,)),
-                        ring)
-                else:  # public threshold (ring-encoded array)
-                    h = h.add_public(t)
-                pending_sign_threshold = None
-            if fused_rounds():
-                # 1 online round: multiply-open + local Alg-4 (activation.py)
-                _, msb_a = msb_extract_arith(h, parties,
-                                             tag=f"sign{idx}.msb")
-                bits = sign_from_msb_arith(msb_a)
-            else:
-                msb = msb_extract(h, parties, tag=f"sign{idx}.msb")
-                bits = sign_from_msb(msb, parties, ring, tag=f"sign{idx}")
-            # keep {0,1} if maxpool follows (fused path); else lift to ±1
-            nxt = model.ops[idx + 1]["op"] if idx + 1 < len(model.ops) else None
-            if nxt == "maxpool":
-                h = bits  # §3.6 fusion consumes the indicator bits
-            else:
-                h = bits.mul_public_int(2).add_public(
-                    jnp.asarray(-1, ring.signed_dtype).astype(ring.dtype))
-            prev_sign = True
-        elif kind == "relu":
-            if fused_rounds():
-                _, msb_a = msb_extract_arith(h, parties,
-                                             tag=f"relu{idx}.msb")
-                h = relu_from_msb_arith(h, msb_a, parties, tag=f"relu{idx}")
-            else:
-                msb = msb_extract(h, parties, tag=f"relu{idx}.msb")
-                h = relu_from_msb(h, msb, parties, tag=f"relu{idx}")
-            prev_sign = False
-        elif kind == "affine":
-            from .linear import mul, mul_truncate
-            if model.weights == "public":
-                # public BN affine: local mult by the encoded scale (2f),
-                # truncate, public shift — no multiplication protocol
-                h = RSS(h.shares * jnp.asarray(op["pub_scale"]), ring)
-                h = truncate(h, parties, tag=f"aff{idx}.tr")
-                h = h.add_public(jnp.asarray(op["pub_shift"]))
-            elif fused_rounds():
-                h = mul_truncate(h, op["scale"], parties, tag=f"aff{idx}")
-                h = h + op["shift"]
-            else:
-                h = truncate(mul(h, op["scale"], parties, tag=f"aff{idx}"),
-                             parties, tag=f"aff{idx}.tr")
-                h = h + op["shift"]
-            prev_sign = False
-        elif kind == "maxpool":
-            if prev_sign:
-                bits = sign_maxpool_fused(h, parties, tag=f"mp{idx}")
-                h = bits.mul_public_int(2).add_public(
-                    jnp.asarray(-1, ring.signed_dtype).astype(ring.dtype))
-                prev_sign = True
-            else:
-                h = secure_maxpool(h, parties, tag=f"mp{idx}")
-        elif kind == "flatten":
+        if kind == "flatten":      # a reshape: no ledger head, no scope
             b = int(h.shape[0])
             h = h.reshape(b, int(np.prod(h.shape[1:])))
+            continue
+        # one named scope per ledger head, so device time joins the ledger
+        with comm.scope(f"{_HEADS[kind]}{idx}") as head:
+            if kind in ("conv", "sepconv", "fc"):
+                # product scale: input(±1 int: 0 | fixed: f) + W(f) => f or 2f
+                binary_in = op.get("binary_in", False)
+                if model.binary_linear == "off" and binary_in:
+                    # binarization-unaware ablation: lift ±1 to scale f and
+                    # pay the full arithmetic opening
+                    h = h.mul_public_int(jnp.asarray(ring.scale, ring.dtype))
+                    binary_in = False
+                if model.weights == "public":
+                    h = _infer_linear_public(h, op, parties, idx, ring,
+                                             binary_in)
+                else:
+                    # the compile-time solver may pin the engine choice per
+                    # op (cost_model.annotate_model); absent that, the
+                    # model-wide routing mode decides
+                    h = _infer_linear_shared(
+                        h, op, parties, idx, ring, binary_in,
+                        binary_engine=op.get(
+                            "engine", model.binary_linear == "auto"))
+                prev_sign = False
+                pending_sign_threshold = (op.get("sign_threshold")
+                                          if model.weights == "shared"
+                                          else op.get("pub_thresh"))
+            elif kind == "sign":
+                if pending_sign_threshold is not None:
+                    t = pending_sign_threshold
+                    if isinstance(t, RSS):
+                        h = RSS(h.shares + t.shares.reshape(
+                            (h.shares.shape[0],) + (1,) * (h.ndim - 1)
+                            + (-1,)), ring)
+                    else:  # public threshold (ring-encoded array)
+                        h = h.add_public(t)
+                    pending_sign_threshold = None
+                if fused_rounds():
+                    # 1 online round: multiply-open + local Alg-4
+                    # (activation.py)
+                    with comm.scope(f"{head}.msb") as tag:
+                        _, msb_a = msb_extract_arith(h, parties, tag=tag)
+                    bits = sign_from_msb_arith(msb_a)
+                else:
+                    with comm.scope(f"{head}.msb") as tag:
+                        msb = msb_extract(h, parties, tag=tag)
+                    bits = sign_from_msb(msb, parties, ring, tag=head)
+                # keep {0,1} if maxpool follows (fused path); else lift to ±1
+                nxt = (model.ops[idx + 1]["op"] if idx + 1 < len(model.ops)
+                       else None)
+                if nxt == "maxpool":
+                    h = bits  # §3.6 fusion consumes the indicator bits
+                else:
+                    h = bits.mul_public_int(2).add_public(
+                        jnp.asarray(-1, ring.signed_dtype).astype(ring.dtype))
+                prev_sign = True
+            elif kind == "relu":
+                fused = fused_rounds()
+                with comm.scope(f"{head}.msb") as tag:
+                    msb = (msb_extract_arith(h, parties, tag=tag)[1] if fused
+                           else msb_extract(h, parties, tag=tag))
+                relu = relu_from_msb_arith if fused else relu_from_msb
+                h = relu(h, msb, parties, tag=head)
+                prev_sign = False
+            elif kind == "affine":
+                from .linear import mul, mul_truncate
+                if model.weights == "public":
+                    # public BN affine: local mult by the encoded scale (2f),
+                    # truncate, public shift — no multiplication protocol
+                    h = RSS(h.shares * jnp.asarray(op["pub_scale"]), ring)
+                    with comm.scope(f"{head}.tr") as tag:
+                        h = truncate(h, parties, tag=tag)
+                    h = h.add_public(jnp.asarray(op["pub_shift"]))
+                elif fused_rounds():
+                    h = mul_truncate(h, op["scale"], parties, tag=head)
+                    h = h + op["shift"]
+                else:
+                    h = mul(h, op["scale"], parties, tag=head)
+                    with comm.scope(f"{head}.tr") as tag:
+                        h = truncate(h, parties, tag=tag)
+                    h = h + op["shift"]
+                prev_sign = False
+            elif kind == "maxpool":
+                if prev_sign:
+                    bits = sign_maxpool_fused(h, parties, tag=head)
+                    h = bits.mul_public_int(2).add_public(
+                        jnp.asarray(-1, ring.signed_dtype).astype(ring.dtype))
+                    prev_sign = True
+                else:
+                    h = secure_maxpool(h, parties, tag=head)
     if reveal_output:
-        return reveal(h, tag="output", decode=True)
+        with comm.scope("output") as tag:
+            return reveal(h, tag=tag, decode=True)
     return h
 
 

@@ -29,16 +29,19 @@ stack reports through:
 :func:`attribution`
     The predicted-vs-measured report: one row per compiled layer
     joining the §15 cost-model prediction (``model.predicted``), the
-    live ``CommLedger`` grouped by layer tag, and the measured online
-    span time distributed by predicted time share.  The per-row
-    measured wire bytes sum to the ledger total *exactly* (pinned in
-    tests/test_telemetry.py) — the report can never disagree with the
-    accounting it summarizes.
+    live ``CommLedger`` grouped by layer tag, and the device time per
+    layer head that the caller measured in a profiler trace (the
+    ``jax.named_scope`` of every ledger tag, ``comm.scope``).  The
+    per-row measured wire bytes sum to the ledger total *exactly*
+    (pinned in tests/test_telemetry.py) — the report can never disagree
+    with the accounting it summarizes.
 
 Disabled-mode cost contract: with no tracer/registry installed every
-hook in the runtime (transport movement ops, TapePool accounting,
-CompiledDecodeStep, Verifier.check) is a single ``is None`` module
-attribute test — no allocation, no clock read, no string formatting.
+metrics hook in the runtime (transport movement ops, TapePool
+accounting, CompiledDecodeStep, Verifier.check) is a single ``is None``
+module attribute test — no allocation, no clock read, no string
+formatting — and a :func:`span` is one ``jax.profiler.TraceAnnotation``
+(about a microsecond when no profiler runs) and nothing else.
 ``secure.obs.*`` rows in BENCH_secure_e2e.json pin the end-to-end cost
 of both states (off within noise of the untouched baseline, full
 tracing within 15%).
@@ -51,16 +54,20 @@ import json
 import math
 import time
 
+import jax
+
 from . import comm
 
 __all__ = ["Span", "Tracer", "tracing", "tracer", "span", "enabled",
-           "MetricsRegistry", "collecting", "metrics", "inc", "gauge",
-           "observe", "movement", "attribution", "AttributionReport",
-           "AttributionRow", "ledger_groups", "validate_chrome_trace",
-           "PHASES"]
+           "profiler_name", "MetricsRegistry", "collecting", "metrics",
+           "inc", "gauge", "observe", "movement", "attribution",
+           "AttributionReport", "AttributionRow", "ledger_groups",
+           "validate_chrome_trace", "PHASES"]
 
 # span taxonomy (DESIGN.md §17): every span names one of these categories
 PHASES = ("setup", "compile", "offline", "online", "verify", "report")
+# the program's spans in a profiler trace (beside the harness's ``bench.*``)
+PROFILER_PREFIX = "cbnn."
 
 _US = 1e6   # trace-event timestamps are microseconds
 
@@ -248,8 +255,6 @@ def validate_chrome_trace(trace: dict) -> None:
 _TRACER: Tracer | None = None
 _METRICS: "MetricsRegistry | None" = None
 
-_NULL = contextlib.nullcontext()
-
 
 def tracer() -> Tracer | None:
     return _TRACER
@@ -259,12 +264,28 @@ def enabled() -> bool:
     return _TRACER is not None or _METRICS is not None
 
 
+def profiler_name(name: str) -> str:
+    """The profiler-trace name of span ``name``: ``cbnn.`` + the name
+    without its ``[i]`` index (``tape_refill[3]`` -> ``cbnn.tape_refill``),
+    so every instance of one span shares one name in the trace."""
+    return PROFILER_PREFIX + name.split("[", 1)[0]
+
+
 def span(name: str, cat: str = "online", lane: str = "main", **args):
-    """Module-level span: records on the active tracer, free when none
-    is installed (returns a shared null context)."""
+    """Module-level span.  Always a ``jax.profiler.TraceAnnotation``
+    named :func:`profiler_name` (about a microsecond when no profiler
+    runs), so the program's spans land in a profiler trace on the device
+    ops' clock; with a tracer installed, also a :class:`Span` on it."""
+    ann = jax.profiler.TraceAnnotation(profiler_name(name))
     if _TRACER is None:
-        return _NULL
-    return _TRACER.span(name, cat, lane, **args)
+        return ann
+    return _annotated(ann, _TRACER.span(name, cat, lane, **args))
+
+
+@contextlib.contextmanager
+def _annotated(ann, inner):
+    with ann, inner as s:
+        yield s
 
 
 @contextlib.contextmanager
@@ -485,7 +506,7 @@ class AttributionRow:
     meas_bytes: int
     pre_bytes: int            # measured offline bytes of the group
     share: float              # meas_bytes / ledger online total
-    attr_ms: float | None     # measured online wall time x predicted share
+    device_ms: float | None   # device ms of the head's scope (caller's)
     tags: tuple = ()          # the ledger tags folded into this row
     has_pred: bool = True     # False: ledger-only group (e.g. verify)
 
@@ -515,17 +536,17 @@ class AttributionReport:
     def render(self) -> str:
         """The human-readable predicted-vs-measured table."""
         hdr = (f"{'layer':<16} {'path':<22} {'pred r/B':>16} "
-               f"{'meas r/B':>16} {'Δ':>3} {'%B':>6} {'attr ms':>8}")
+               f"{'meas r/B':>16} {'Δ':>3} {'%B':>6} {'dev ms':>8}")
         lines = [hdr, "-" * len(hdr)]
         for r in self.rows:
             d = "ok" if r.exact else "!!"
-            attr = f"{r.attr_ms:8.2f}" if r.attr_ms is not None else \
+            dev = f"{r.device_ms:8.2f}" if r.device_ms is not None else \
                 f"{'-':>8}"
             lines.append(
                 f"{r.name:<16} {r.path:<22} "
                 f"{r.pred_rounds:>4}/{r.pred_bytes:>11,} "
                 f"{r.meas_rounds:>4}/{r.meas_bytes:>11,} {d:>3} "
-                f"{r.share * 100:>5.1f}% {attr}")
+                f"{r.share * 100:>5.1f}% {dev}")
         foot = (f"{'total':<16} {'':<22} "
                 f"{sum(r.pred_rounds for r in self.rows):>4}/"
                 f"{sum(r.pred_bytes for r in self.rows):>11,} "
@@ -566,27 +587,27 @@ def ledger_groups(led: comm.CommLedger) -> dict[str, list]:
 
 
 def attribution(predicted, led: comm.CommLedger, *,
+                layer_ms: dict | None = None,
                 online_s: float | None = None,
                 deployment=None) -> AttributionReport:
     """Join the cost-model prediction (a ``CostReport`` traced at the
     *serving* batch shape — e.g. ``cost_model.model_cost(model,
     (B,) + shape)``, or ``None`` when no per-layer prediction exists,
-    as on the LM path), the live per-query ledger, and the measured
-    online wall time into the per-layer predicted-vs-measured table.
+    as on the LM path) and the live per-query ledger into the per-layer
+    predicted-vs-measured table.
 
-    ``online_s`` (measured seconds per query, e.g. the tracer's online
-    phase total / queries) is distributed across rows by each row's
-    *predicted* time share under ``deployment`` (default LAN; measured
-    byte share when no prediction exists) — wall attribution below one
-    compiled program is a model-weighted split, and the column says so.
-    Measured rounds/bytes per row come from the ledger alone and sum to
-    its totals exactly."""
+    ``layer_ms`` maps a ledger head (``l3``, ``sign4``, ...) to the
+    device milliseconds per query that the caller measured inside that
+    head's ``jax.named_scope`` in a profiler trace; a row without one
+    shows ``-``.  Nothing here estimates a time.  ``online_s`` and
+    ``deployment`` only label the report.  Measured rounds/bytes per row
+    come from the ledger alone and sum to its totals exactly."""
     from . import cost_model
 
     dep = cost_model.resolve_deployment(deployment) or cost_model.LAN
+    layer_ms = layer_ms or {}
     groups = ledger_groups(led)
     rows: list[AttributionRow] = []
-    times = []
     entries = predicted.entries if predicted is not None else []
     for e in entries:
         head = e.name.split(" ", 1)[0]
@@ -595,22 +616,18 @@ def attribution(predicted, led: comm.CommLedger, *,
         rows.append(AttributionRow(
             name=e.name, path=path, pred_rounds=e.cost.rounds,
             pred_bytes=e.cost.nbytes, meas_rounds=g[0], meas_bytes=g[1],
-            pre_bytes=g[3], share=0.0, attr_ms=None, tags=tuple(g[4])))
-        times.append(e.cost.time(dep))
+            pre_bytes=g[3], share=0.0, device_ms=layer_ms.get(head),
+            tags=tuple(g[4])))
     for head in sorted(groups):   # ledger-only groups (e.g. verify.digest)
         g = groups[head]
         rows.append(AttributionRow(
             name=head, path="-", pred_rounds=0, pred_bytes=0,
             meas_rounds=g[0], meas_bytes=g[1], pre_bytes=g[3], share=0.0,
-            attr_ms=None, tags=tuple(g[4]), has_pred=False))
-        times.append(0.0)
+            device_ms=layer_ms.get(head), tags=tuple(g[4]),
+            has_pred=False))
     total_b = max(led.nbytes, 1)
-    total_t = sum(times)
-    for r, t in zip(rows, times):
+    for r in rows:
         r.share = r.meas_bytes / total_b
-        if online_s is not None:
-            w = t / total_t if total_t > 0 else r.share
-            r.attr_ms = online_s * 1e3 * w
     return AttributionReport(rows=rows, ledger_rounds=led.rounds,
                              ledger_bytes=led.nbytes, online_s=online_s,
                              deployment=dep.name)

@@ -422,8 +422,10 @@ def emit_obs(args, tracer, reg, led, predicted=None, model=None,
     """Write the ``--trace``/``--metrics-*`` artifacts and print the
     predicted-vs-measured attribution table (DESIGN.md §17).  Measured
     rounds/bytes per row come straight from the live ledger and sum to
-    its totals exactly; measured wall time (``online_s`` over
-    ``queries`` units) is split by predicted time share."""
+    its totals exactly.  Its device-time column needs a profiler trace
+    reduced by layer head, which this entry point does not take: it
+    shows ``-``, and ``online_s`` over ``queries`` units only labels
+    the report."""
     from repro.core import telemetry
     if tracer is None and reg is None:
         return None

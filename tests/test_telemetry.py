@@ -7,8 +7,9 @@ Pinned acceptance contracts of PR 10:
 * the attribution report's per-layer measured wire bytes sum to the live
   ``CommLedger`` totals EXACTLY (classifier zoo including a separable
   net, with and without a verify-digest ledger row);
-* telemetry disabled is a no-op (shared null context, no spans, no
-  samples) and enabled telemetry never changes model outputs —
+* telemetry disabled records nothing (a span is only a profiler
+  annotation: no Span, no samples) and enabled telemetry never changes
+  model outputs —
   bit-identical logits under both transports (the mesh case runs in a
   party subprocess with fake devices, like the other mesh tests).
 """
@@ -40,11 +41,12 @@ def _model(net, **kw):
 def test_disabled_mode_is_noop():
     assert telemetry.tracer() is None and telemetry.metrics() is None
     assert not telemetry.enabled()
-    # module-level span returns the SHARED null context: no allocation
+    # module-level span is only a profiler annotation: no Span recorded
     a, b = telemetry.span("x"), telemetry.span("y", cat="compile")
-    assert a is b is telemetry._NULL
+    assert isinstance(a, jax.profiler.TraceAnnotation)
+    assert isinstance(b, jax.profiler.TraceAnnotation)
     with a as s:
-        assert s is None
+        assert not isinstance(s, telemetry.Span)
     # metric hooks are silent no-ops
     telemetry.inc("c")
     telemetry.gauge("g", 1.0)
@@ -257,7 +259,9 @@ def test_attribution_measured_matches_ledger_exactly(net):
     shape = (2,) + INPUT_SHAPES[net]
     led = secure_infer_cost(model, shape)
     pred = cost_model.model_cost(model, shape)
-    rep = telemetry.attribution(pred, led, online_s=0.5)
+    heads = [e.name.split(" ", 1)[0] for e in pred.entries]
+    layer_ms = {h: float(i + 1) for i, h in enumerate(heads)}
+    rep = telemetry.attribution(pred, led, layer_ms=layer_ms, online_s=0.5)
     # per-row measured wire bytes sum to the live ledger totals EXACTLY
     assert sum(r.meas_bytes for r in rep.rows) == led.nbytes
     assert sum(r.meas_rounds for r in rep.rows) == led.rounds
@@ -270,8 +274,11 @@ def test_attribution_measured_matches_ledger_exactly(net):
     for r in rep.rows:
         assert (r.pred_rounds, r.pred_bytes) == (r.meas_rounds,
                                                  r.meas_bytes), r.name
-    # measured wall time distributes fully across rows
-    assert sum(r.attr_ms for r in rep.rows) == pytest.approx(500.0)
+    # the device-time column is the caller's per-head ms, row by row,
+    # and nothing is spread over rows that were given none
+    assert [r.device_ms for r in rep.rows] == [layer_ms[h] for h in heads]
+    assert sum(r.device_ms for r in rep.rows) == pytest.approx(
+        sum(layer_ms.values()))
     assert "total" in rep.render()
 
 
@@ -297,7 +304,12 @@ def test_attribution_without_prediction_uses_byte_share():
     rep = telemetry.attribution(None, led, online_s=1.0)
     assert all(not r.has_pred for r in rep.rows)
     assert sum(r.meas_bytes for r in rep.rows) == led.nbytes
-    assert sum(r.attr_ms for r in rep.rows) == pytest.approx(1000.0)
+    assert sum(r.share for r in rep.rows) == pytest.approx(1.0)
+    # a wall time is a label, never split into per-layer times
+    assert all(r.device_ms is None for r in rep.rows)
+    assert rep.as_dict()["online_s"] == 1.0
+    rows = rep.render().splitlines()[2:2 + len(rep.rows)]
+    assert all(line.endswith(" -") for line in rows)
     assert rep.as_dict()["ledger_bytes"] == led.nbytes
 
 

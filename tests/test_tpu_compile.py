@@ -115,3 +115,42 @@ def test_bin_grouped_matmul_compiles(one_chip, m, c):
         [_spec((S, c, m, k), jnp.uint32, one_chip),
          _spec((c, k, n), jnp.uint32, one_chip),
          _spec((L_PUBLIC, c, k, n), jnp.int8, one_chip)])
+
+
+
+def _dense_call(one_chip):
+    m, k, n = DENSE[-1]
+    return (lambda x, *w: rss_matmul_parts(x, WeightLimbs(*w),
+                                           interpret=False),
+            [_spec((S, m, k), jnp.uint32, one_chip),
+             *[_spec((S, k, n), jnp.uint32, one_chip)] * 2,
+             *[_spec((S, 4, _tile(k), _tile(n)), jnp.int8, one_chip)] * 2])
+
+
+def _grouped_call(one_chip):
+    (m, c), k, n = GROUPED[-1], 9, 1
+    return (lambda x, *w: grouped_rss_matmul_parts(
+                x, GroupedWeightLimbs(*w), interpret=False),
+            [_spec((S, c, m, k), jnp.uint32, one_chip),
+             *[_spec((S, c, k, n), jnp.uint32, one_chip)] * 2,
+             *[_spec((S, 4, c, k, n), jnp.int8, one_chip)] * 2])
+
+
+@pytest.mark.parametrize("kernel,call", [("_rss_matmul_call", _dense_call),
+                                         ("_grouped_shared_call",
+                                          _grouped_call)])
+def test_kernel_keeps_its_name_inside_a_ledger_scope(one_chip, kernel, call):
+    """Inside the executor's scopes (``comm.scope``) a kernel launch keeps
+    the instruction name the trace reduction matches, and carries the
+    scope path in its ``op_name``."""
+    from repro.core import comm
+    fn, args = call(one_chip)
+
+    def scoped(*a):
+        with comm.scope("l3"), comm.scope("l3.pwconv"):
+            return fn(*a)
+    text = jax.jit(scoped).lower(*args).compile().as_text()
+    (line,) = [ln for ln in text.splitlines()
+               if KERNEL_CALL in ln and " = " in ln]
+    assert line.strip().startswith(f"%{kernel}.")
+    assert "/l3/l3.pwconv/" in line.split("op_name=", 1)[1]
