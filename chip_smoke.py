@@ -9,7 +9,8 @@ a seed.  Default phases, all on one chip:
   (a) fail unless JAX's default device is a TPU;
   (b) CifarNet2, shared weights, inline material, local backend: the
       compiled runner holds one ``tpu_custom_call`` per kernel launch the
-      cost model lists, its logits are bit-identical to the jnp reference
+      cost model lists (the dense ones: the depthwise halves are direct
+      tap products), its logits are bit-identical to the jnp reference
       lowering (``--no-kernel``) and within the fixed-point bounds of
       ``bnn_forward``;
   (c) the same with public weights;
@@ -163,11 +164,14 @@ def bnn_inline(weights, batch, queries):
         model, (batch,) + INPUT_SHAPES[NET]).kernel_requests()
     run, _ = make_runner(model, "local", batch)
     compiled, csec = timed_compile(run, keys, xs)
-    n_calls = compiled.as_text().count(KERNEL_CALL)
+    text = compiled.as_text()
+    n_calls = text.count(KERNEL_CALL)
     log(f"{weights}: {len(reqs)} kernel launches listed, {n_calls} "
         f"tpu_custom_call in the compiled runner; compile {csec:.1f} s")
-    check(n_calls >= len(reqs),
-          f"{n_calls} tpu_custom_call < {len(reqs)} listed launches")
+    check(n_calls == len(reqs),
+          f"{n_calls} tpu_custom_call != {len(reqs)} listed launches")
+    check("_grouped_shared_call" not in text,
+          "a depthwise half launched the grouped shared-weight kernel")
     out, wall = serve_queries(compiled, keys, xs, queries)
     log(f"{weights}: {queries} queries of batch {batch} in {wall:.3f} s")
 

@@ -321,18 +321,17 @@ def _linear_candidates(op: dict, shape, nxt_shape, *, public: bool,
 def _linear_requests(op: dict, m: int, kk: int, nn: int, *,
                      public: bool) -> list:
     """(family, m, k, n, n_limbs, channels) tuples for this op's kernel
-    launches, skipping shapes the dispatchers send to the ref path."""
+    launches, skipping shapes the dispatchers send to the ref path.  A
+    shared-weight depthwise half launches nothing: it is a direct tap
+    product (`linear._grouped_conv_parts`)."""
     kind = op["op"]
     ws = _w_shapes(op)
     reqs = []
     if kind == "sepconv":
         dkh, dkw, _, cin = ws[0]
-        if m >= _MIN_KERNEL_DIM:
-            if public:
-                reqs.append(("bin_grouped_matmul", m, dkh * dkw, 1,
-                             _public_limbs(op, 0), cin))
-            else:
-                reqs.append(("grouped_rss_matmul", m, dkh * dkw, 1, 4, cin))
+        if public and m >= _MIN_KERNEL_DIM:
+            reqs.append(("bin_grouped_matmul", m, dkh * dkw, 1,
+                         _public_limbs(op, 0), cin))
         if min(m, kk, nn) >= _MIN_KERNEL_DIM:
             fam = "bin_rss_matmul" if public else "rss_matmul"
             reqs.append((fam, m, kk, nn,
@@ -353,8 +352,7 @@ def _lookup_kcfgs(op: dict, reqs: list, cache_path=None) -> list | None:
         by_family[fam] = autotune.lookup(fam, m, kk, nn, n_limbs=limbs,
                                          channels=ch, path=cache_path)
     if op["op"] == "sepconv":
-        kcfg = [by_family.get("bin_grouped_matmul")
-                or by_family.get("grouped_rss_matmul"),
+        kcfg = [by_family.get("bin_grouped_matmul"),
                 by_family.get("bin_rss_matmul")
                 or by_family.get("rss_matmul")]
     else:

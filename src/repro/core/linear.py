@@ -308,42 +308,52 @@ def _im2col(x, kh: int, kw: int, stride: int, padding: int):
 
 
 def _grouped_conv_parts(x: RSS, w: RSS, stride: int, padding: int,
-                        groups: int, w_limbs=None, kcfg=None):
-    """Additive per-channel (depthwise) product stack: im2col patches
-    contracted against each channel's own kernel, fused-operand Alg 2.
+                        groups: int):
+    """Additive per-channel (depthwise) product stack, fused-operand Alg 2
+    as a direct kh·kw-tap ring multiply-accumulate:
+
+        z_i = Σ_taps x_i[tap]·(w_i + w_{i+1})[tap] + x_{i+1}[tap]·w_i[tap]
+
+    on strided views of the once-padded shares, each tap's per-channel
+    weight broadcast along the channel axis (and over the depthwise
+    multiplier m, for the output layout ``out[..., c*mult + m]``).  Exact
+    mod 2^32.  The contraction is kh·kw deep with one output column per
+    channel, a shape no MXU tiling suits, so it stays elementwise uint32
+    work with no im2col copies.
 
     Returns the (S, B, Ho, Wo, Cout) parts stack — local compute, no comm;
-    callers add bias parts and reshare.  With ``w_limbs`` (a
-    `kernels.bin_rss_matmul.GroupedWeightLimbs` cached at setup) the whole
-    3-party grouped product runs in one Pallas launch instead of the
-    per-party einsum; both paths are exact mod 2^32 (bit-identical)."""
+    callers add bias parts and reshare."""
     kh, kw, cin_g, cout = (int(d) for d in w.shape)
-    b = int(x.shape[0])
     cin = int(x.shape[3])
     assert groups == cin and cin_g == 1 and cout % groups == 0
     mult = cout // groups
-    cols, ho, wo = _im2col_rss(x, kh, kw, stride, padding)  # (...,kh*kw*Cin)
-    cols4 = cols.reshape(b, ho, wo, kh * kw, cin)
     t = transport.current()
-    if w_limbs is not None:
-        from ..kernels.ops import grouped_rss_matmul_op
-        z = grouped_rss_matmul_op(t.own_view(cols4.shares),
-                                  t.next_view(cols4.shares), w_limbs,
-                                  cfg=kcfg)
-        return z.reshape(z.shape[0], b, ho, wo, cout)
-    # einsum over the patch dim per channel: out[...,c*mult+m]
-    slots = t.rss_slots
-    ws_full = w.reshape(kh * kw, 1, cout).shares.reshape(slots, kh * kw,
-                                                         cin, mult)
-    xo, xn = t.own_view(cols4.shares), t.next_view(cols4.shares)
-    wo_, wn = t.own_view(ws_full), t.next_view(ws_full)
+    with jax.named_scope("taps"):
+        xs = x.shares
+        if padding:
+            xs = jnp.pad(xs, ((0, 0), (0, 0), (padding, padding),
+                              (padding, padding), (0, 0)))
+        xo, xn = t.own_view(xs), t.next_view(xs)
+        s, b, hp, wp, _ = (int(d) for d in xo.shape)
+        ho = (hp - kh) // stride + 1
+        wo = (wp - kw) // stride + 1
+        # (S, kh, kw, C, mult): own weight share and the fused operand
+        ws = t.own_view(w.shares).reshape(s, kh, kw, cin, mult)
+        wf = ws + t.next_view(w.shares).reshape(s, kh, kw, cin, mult)
 
-    def dw(a, bmat):
-        return jnp.einsum("bhwkc,kcm->bhwcm", a, bmat,
-                          preferred_element_type=x.ring.dtype)
-    z = jnp.stack([dw(xo[i], wo_[i] + wn[i]) + dw(xn[i], wo_[i])
-                   for i in range(xo.shape[0])])
-    return z.reshape(z.shape[0], b, ho, wo, cout)
+        def tap(a, i, j):
+            v = jax.lax.slice(
+                a, (0, 0, i, j, 0),
+                (s, b, i + stride * (ho - 1) + 1, j + stride * (wo - 1) + 1,
+                 cin), (1, 1, stride, stride, 1))
+            return v[..., None]                  # (S, B, Ho, Wo, C, 1)
+
+        def wtap(a, i, j):
+            return a[:, i, j].reshape(s, 1, 1, 1, cin, mult)
+
+        z = sum(tap(xo, i, j) * wtap(wf, i, j) + tap(xn, i, j) * wtap(ws, i, j)
+                for i in range(kh) for j in range(kw))
+        return z.reshape(s, b, ho, wo, cout)
 
 
 def conv2d(x: RSS, w: RSS, parties: Parties, stride: int = 1,
@@ -351,20 +361,20 @@ def conv2d(x: RSS, w: RSS, parties: Parties, stride: int = 1,
            w_limbs=None, kcfg=None) -> RSS:
     """Secure 2-D convolution. x: (B,H,W,Cin), w: (kh,kw,Cin/groups,Cout).
 
-    ``w_limbs`` holds the setup-time limb cache: a
-    `kernels.rss_matmul.WeightLimbs` of the (kh·kw·Cin, Cout) weight
-    matrix (groups == 1), or a `GroupedWeightLimbs` for the depthwise case
-    (groups == Cin) — either way the im2col patches run through the fused
-    3-party kernel.  Depthwise costs one reshare round for the whole layer,
-    same as dense."""
+    ``w_limbs`` holds the setup-time limb cache of the (kh·kw·Cin, Cout)
+    weight matrix (a `kernels.rss_matmul.WeightLimbs`, groups == 1): the
+    im2col patches run through the fused 3-party kernel.  The depthwise
+    case (groups == Cin) is the direct tap product of
+    `_grouped_conv_parts`, which needs no cache (``w_limbs`` and ``kcfg``
+    are ignored there).  Depthwise costs one reshare round for the whole
+    layer, same as dense."""
     kh, kw, cin_g, cout = (int(d) for d in w.shape)
     if groups == 1:
         cols, ho, wo = _im2col_rss(x, kh, kw, stride, padding)
         wmat = w.reshape(kh * kw * cin_g, cout)
         return matmul(cols, wmat, parties, tag=tag, w_limbs=w_limbs,
                       kcfg=kcfg)
-    z = _grouped_conv_parts(x, w, stride, padding, groups, w_limbs=w_limbs,
-                            kcfg=kcfg)
+    z = _grouped_conv_parts(x, w, stride, padding, groups)
     return _reshare(z, x.ring, parties, tag=tag)
 
 
@@ -471,9 +481,11 @@ def bin_conv2d(x: RSS, w: RSS | PublicTensor, parties: Parties,
     """Binary-domain secure conv: im2col + `bin_matmul` (groups == 1) or the
     per-channel grouped contraction (groups == Cin, the depthwise half of a
     sepconv) — either way the post-Sign layer costs one reshare round
-    (shared weights) or nothing at all (public weights).  Public grouped
-    convs run locally on every held slot, through the grouped public-limb
-    kernel when ``w.limbs`` carries a `PublicGroupedLimbs` cache."""
+    (shared weights) or nothing at all (public weights).  Shared grouped
+    convs are the direct tap product of `_grouped_conv_parts` (``w_limbs``
+    and ``kcfg`` are ignored there).  Public grouped convs run locally on
+    every held slot, through the grouped public-limb kernel when
+    ``w.limbs`` carries a `PublicGroupedLimbs` cache."""
     if isinstance(w, PublicTensor):
         assert bias_parts is None, \
             "public weights take bias_public (a public encoding), not " \
@@ -513,8 +525,7 @@ def bin_conv2d(x: RSS, w: RSS | PublicTensor, parties: Parties,
         # so the whole grouped layer is the one reshare round — same parts
         # arithmetic (and PRF draw order) as conv2d's grouped branch, hence
         # bit-identical to the generic route
-        z = _grouped_conv_parts(x, w, stride, padding, groups,
-                                w_limbs=w_limbs, kcfg=kcfg)
+        z = _grouped_conv_parts(x, w, stride, padding, groups)
         if bias_parts is not None:
             z = z + bias_parts
         return _reshare(z, x.ring, parties, tag=tag)

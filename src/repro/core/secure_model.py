@@ -41,9 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.bin_rss_matmul import (grouped_weight_limbs,
-                                      public_grouped_limbs,
-                                      public_weight_limbs)
+from ..kernels.bin_rss_matmul import public_grouped_limbs, public_weight_limbs
 from ..kernels.rss_matmul import precompute_weight_limbs
 from ..nn.bnn import ALL_NETS, INPUT_SHAPES, L
 from . import comm, transport
@@ -96,9 +94,9 @@ def compile_secure(params: dict, net: str, key,
     weight-share stack (and its fused operand w_i + w_{i+1}) into cached
     int8 limbs, so `secure_infer` routes the layer through the single-launch
     3-party Pallas kernel — weight limbs are never recomputed per query.
-    Depthwise (grouped) convs get per-channel grouped limb caches
-    (`kernels.bin_rss_matmul.grouped_weight_limbs`) and run through the
-    grouped kernel instead of the per-party einsum.
+    Depthwise (grouped) convs with shared weights need no cache: they run
+    as a direct tap product of the shares (`linear._grouped_conv_parts`)
+    on either setting.
 
     ``weights="public"`` keeps model parameters in the clear (the
     private-input / public-model deployment, DESIGN.md §11): linear layers
@@ -293,19 +291,17 @@ def _public_weight(w: np.ndarray, kind: str, part_idx: int, ring: RingSpec,
 
 def _weight_limbs_for(w: RSS, kind: str, part_idx: int):
     """Setup-time limb cache for one weight-share stack: dense layers get
-    `WeightLimbs` for the fused matmul kernel; the depthwise half of a
-    sepconv gets the per-channel `GroupedWeightLimbs` for the grouped
-    kernel (bnn sepconvs use depthwise multiplier 1, so Cout == Cin)."""
+    `WeightLimbs` for the fused matmul kernel.  The depthwise half of a
+    sepconv gets None: it runs as a direct tap product on the weight
+    shares themselves (`linear._grouped_conv_parts`), with no kernel
+    launch to feed."""
     if kind == "fc":
         return precompute_weight_limbs(w.shares)
     if kind == "conv" or (kind == "sepconv" and part_idx == 1):
         kh, kw, cin_g, cout = (int(d) for d in w.shape)
         return precompute_weight_limbs(
             w.shares.reshape(3, kh * kw * cin_g, cout))
-    kh, kw, cin_g, cout = (int(d) for d in w.shape)
-    assert cin_g == 1, "depthwise kernels are (kh, kw, 1, Cin)"
-    return grouped_weight_limbs(
-        w.shares.reshape(3, kh * kw, cout, 1).transpose(0, 2, 1, 3))
+    return None
 
 
 # the tag of each linear kind's product (``pw``: a sepconv's pointwise half)
@@ -329,7 +325,7 @@ def _infer_linear_shared(h: RSS, op: dict, parties: Parties, idx: int,
     kind = op["op"]
     if kind == "sepconv":
         # separable: depthwise then pointwise (Alg 2 twice, Fig 3), the
-        # depthwise half on the grouped kernel when limbs are cached.  A
+        # depthwise half as a direct tap product of the shares.  A
         # post-Sign depthwise product is already at scale f — the binary
         # engine runs it as a first-class bin-shared layer (one reshare,
         # no truncation); otherwise the arith route pays the dwtrunc.

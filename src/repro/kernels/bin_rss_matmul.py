@@ -243,14 +243,15 @@ def bin_rss_matmul_parts(x_stack: jax.Array, weights: PublicWeightLimbs, *,
 # A depthwise conv is a *grouped* matmul: channel c contracts its own
 # (M, K=kh·kw) patch matrix against its own tiny (K, mult) kernel.  Under
 # RSS this is far cheaper than a dense conv — the contraction depth is kh·kw
-# instead of kh·kw·Cin — but until ISSUE 6 the depthwise half of every
-# sepconv fell back to a per-party jnp einsum (`_weight_limbs_for` returned
-# None).  The two kernels below put the depthwise half on the same
-# limb-decomposed path as everything else:
+# instead of kh·kw·Cin.  The two kernels below run it on the
+# limb-decomposed path of the dense kernels:
 #
 #   * `grouped_rss_matmul_parts` — SHARED weights: the fused-operand Alg-2
 #     additive products  z_i[c] = x_i[c]·(w_i[c]+w_{i+1}[c]) + x_{i+1}[c]·w_i[c]
-#     per channel, full 4×4 limb grid (both operands are shares).
+#     per channel, full 4×4 limb grid (both operands are shares).  Library
+#     code: the served path computes this product as a direct tap
+#     multiply-accumulate (`core.linear._grouped_conv_parts`), which needs
+#     no patch copies, limbs or launch.
 #   * `bin_grouped_matmul_parts` — PUBLIC weights: every held slot's local
 #     product z_s[c] = x_s[c] @ W[c], with the same adaptive limb collapse
 #     as the dense public kernel (L = 1..4 from the bounded encoding).
@@ -267,8 +268,8 @@ class GroupedWeightLimbs(typing.NamedTuple):
 
     Mirrors `rss_matmul.WeightLimbs` with a leading channel axis: ``ws``
     holds w_i, ``wf`` the fused operand w_i + w_{i+1}, and ``wl``/``wfl``
-    their int8 limbs.  Computed once at model setup (`compile_secure`) from
-    the depthwise kernel reshaped to (3, C, kh·kw, mult)."""
+    their int8 limbs, from the depthwise kernel reshaped to
+    (3, C, kh·kw, mult)."""
 
     ws: jax.Array   # (3, C, K, N) uint32 — w_i per channel
     wf: jax.Array   # (3, C, K, N) uint32 — fused operand w_i + w_{i+1}

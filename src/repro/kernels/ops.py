@@ -10,9 +10,8 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .bin_rss_matmul import (GroupedWeightLimbs, PublicGroupedLimbs,
-                             PublicWeightLimbs, bin_grouped_matmul_parts,
-                             bin_rss_matmul_parts, grouped_rss_matmul_parts)
+from .bin_rss_matmul import (PublicGroupedLimbs, PublicWeightLimbs,
+                             bin_grouped_matmul_parts, bin_rss_matmul_parts)
 from .binary_matmul import binary_binary_matmul, binary_weight_matmul
 from .flash_attention import flash_attention
 from .lowering import KernelConfig
@@ -119,30 +118,6 @@ def _unfold_grouped(out: jax.Array, lead, n: int):
     (matches the per-channel einsum's `...cm` output ordering)."""
     s, c = out.shape[0], out.shape[1]
     return out.transpose(0, 2, 1, 3).reshape((s,) + lead + (c, n))
-
-
-def grouped_rss_matmul_op(x_stack: jax.Array, x_next_stack: jax.Array,
-                          weights: GroupedWeightLimbs,
-                          cfg: KernelConfig | None = None) -> jax.Array:
-    """Depthwise (grouped) additive-product stack from one kernel launch.
-
-    x_stack / x_next_stack: (S, ..., K, C) per-channel patch stacks (K =
-    kh·kw), leading dims folded into M; ``weights`` is the setup-time
-    (3, C, K, N) grouped limb cache — under a pair-carrying transport only
-    the own slot feeds the kernel.  Returns (S, ..., C, N) with
-    z_i[c] = x_i[c]·(w_i[c]+w_{i+1}[c]) + x_{i+1}[c]·w_i[c]."""
-    from ..core import transport
-    t = transport.current()
-    lead = x_stack.shape[1:-2]
-    if not t.carries_pair:
-        # stacked sim: next == roll(own); the kernel rolls the limbs itself
-        w_own, xn = weights, None
-    else:
-        w_own = GroupedWeightLimbs(*(t.own_view(a) for a in weights))
-        xn = _fold_grouped(x_next_stack)
-    out = grouped_rss_matmul_parts(_fold_grouped(x_stack), w_own,
-                                   x_next_stack=xn, cfg=cfg)
-    return _unfold_grouped(out, lead, weights.n)
 
 
 def bin_grouped_matmul_op(x_stack: jax.Array,

@@ -6,8 +6,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import RING32, Parties, share
+from repro.core import RING32, Parties, share, transport
+from repro.core import linear
 from repro.core.linear import PublicTensor, bin_matmul
+from repro.core.rss import RSS
 from repro.core.secure_model import (compile_secure, post_sign_linear_cost,
                                      secure_infer, secure_infer_cost)
 from repro.kernels.bin_rss_matmul import (bin_grouped_matmul_parts,
@@ -193,15 +195,58 @@ def test_bin_engine_bit_identical_to_arith_route(net, shape, batch,
     assert np.array_equal(got, ref)
 
 
-def test_sepconv_grouped_kernel_bit_identical():
-    """The grouped Pallas kernel (use_kernel_dot=True) is bit-identical to
-    the per-party einsum fallback on a sepconv net: same additive parts
-    mod 2^32, same single reshare, same PRF draw order."""
+def _im2col_grouped_parts(x, w, stride, padding, groups):
+    """The per-channel reference route of the depthwise product: im2col
+    patches, then each channel's batched dot against its own kernel
+    (`grouped_rss_matmul_ref`), on the transport's own/next views."""
+    kh, kw, _, cout = (int(d) for d in w.shape)
+    b, cin = int(x.shape[0]), int(x.shape[3])
+    mult = cout // groups
+    t = transport.current()
+    cols, ho, wo = linear._im2col_rss(x, kh, kw, stride, padding)
+
+    def fold(a):    # (S, B, Ho, Wo, K*C) -> (S, C, M, K)
+        return a.reshape(a.shape[0], -1, kh * kw, cin).transpose(0, 3, 1, 2)
+    wst = t.own_view(w.shares).reshape(-1, kh * kw, cin, mult)
+    wn = t.next_view(w.shares).reshape(-1, kh * kw, cin, mult)
+    wl = grouped_weight_limbs(wst.transpose(0, 2, 1, 3))
+    wl = wl._replace(wf=(wst + wn).transpose(0, 2, 1, 3))
+    z = grouped_rss_matmul_ref(fold(t.own_view(cols.shares)), wl,
+                               fold(t.next_view(cols.shares)))
+    return z.transpose(0, 2, 1, 3).reshape(z.shape[0], b, ho, wo, cout)
+
+
+@pytest.mark.parametrize("stride", [1, 2])
+@pytest.mark.parametrize("padding", [0, 1])
+@pytest.mark.parametrize("k", [3, 5])
+@pytest.mark.parametrize("mult", [1, 2])
+def test_direct_depthwise_matches_im2col_reference(stride, padding, k, mult):
+    """The direct tap product of `_grouped_conv_parts` equals im2col +
+    `grouped_rss_matmul_ref` bit for bit, on shares drawn from the whole
+    ring: strides, paddings, kernel sizes, depthwise multipliers."""
+    key = jax.random.PRNGKey(100 * stride + 10 * padding + k + mult)
+    c, b, hw = 3, 2, 11
+    x = RSS(jax.random.bits(key, (3, b, hw, hw, c), jnp.uint32), RING32)
+    w = RSS(jax.random.bits(jax.random.fold_in(key, 1),
+                            (3, k, k, 1, c * mult), jnp.uint32), RING32)
+    got = np.asarray(linear._grouped_conv_parts(x, w, stride, padding, c))
+    ref = np.asarray(_im2col_grouped_parts(x, w, stride, padding, c))
+    ho = (hw + 2 * padding - k) // stride + 1
+    assert got.shape == (3, b, ho, ho, c * mult)
+    assert np.array_equal(got, ref)
+
+
+def test_sepconv_grouped_kernel_bit_identical(monkeypatch):
+    """The served sepconv net (direct depthwise tap product, kernel path
+    on) is bit-identical to the same net with its depthwise halves on the
+    per-channel reference route (im2col + batched dots): same additive
+    parts mod 2^32, same single reshare, same PRF draw order."""
     params = _random_net_params("MnistNet3-sep")
     x = _grid_input((2, 28, 28, 1))
-    a, _ = _run_net(params, "MnistNet3-sep", x)
-    b, _ = _run_net(params, "MnistNet3-sep", x, use_kernel_dot=True)
-    assert np.array_equal(a, b)
+    served, _ = _run_net(params, "MnistNet3-sep", x, use_kernel_dot=True)
+    monkeypatch.setattr(linear, "_grouped_conv_parts", _im2col_grouped_parts)
+    ref, _ = _run_net(params, "MnistNet3-sep", x, use_kernel_dot=True)
+    assert np.array_equal(served, ref)
 
 
 @pytest.mark.parametrize("net,shape,exact", [

@@ -153,6 +153,25 @@ def test_kernel_requests_shapes():
         model, (1,) + INPUT_SHAPES["MnistNet1"]).kernel_requests() == []
 
 
+def test_sepconv_kernel_requests_by_weight_mode():
+    """A shared-weight depthwise half is a direct tap product and requests
+    no kernel launch; a public one still runs the grouped public-limb
+    kernel.  The pointwise halves request their dense launch either way."""
+    net, batch = "MnistNet3-sep", 8
+    shape = (batch,) + INPUT_SHAPES[net]
+    shared = cost_model.model_cost(_model(net, use_kernel_dot=True),
+                                   shape).kernel_requests()
+    public = cost_model.model_cost(
+        _model(net, use_kernel_dot=True, weights="public"),
+        shape).kernel_requests()
+    n_sep = sum(op["op"] == "sepconv" for op in _model(net).ops)
+    assert n_sep > 0
+    assert not [r for r in shared if "grouped" in r[0]]
+    assert [r[0] for r in public].count("bin_grouped_matmul") == n_sep
+    assert ([r for r in shared if r[0] == "rss_matmul"]
+            and [r for r in public if r[0] == "bin_rss_matmul"])
+
+
 def test_kcfg_from_cache_is_bit_identical(tmp_path):
     """A compile that pins autotuned configs (here: forced ref lowering via
     a hand-written cache) must produce bit-identical logits — tuning is

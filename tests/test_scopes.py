@@ -81,6 +81,31 @@ def test_every_ledger_head_is_a_scope_tape(net):
     _check_scopes(_scope_paths(lowered), heads)
 
 
+@pytest.mark.parametrize("binary_linear", ["auto", "off"])
+def test_depthwise_product_runs_under_taps(binary_linear):
+    """Every depthwise half of a separable net computes its product inside
+    a ``taps`` scope nested in its ledger tag (``l{i}.dwconv[.bin]``), and
+    no kernel launch: the trace shows the direct product apart from the
+    reshare."""
+    net = "MnistNet3-sep"
+    params = init_bnn(jax.random.PRNGKey(0), net)
+    model = compile_secure(params, net, jax.random.PRNGKey(1), RING32,
+                           use_kernel_dot=True, binary_linear=binary_linear)
+    shape = (2,) + INPUT_SHAPES[net]
+    keys = Parties.setup(jax.random.PRNGKey(7)).keys
+    x = jax.ShapeDtypeStruct((3,) + shape, RING32.dtype)
+    lowered = jax.jit(lambda k, xs: secure_infer(
+        model, RSS(xs, model.ring), Parties(k))).lower(keys, x)
+    paths = _scope_paths(lowered)
+    dw = {t for t in secure_infer_cost(model, shape).by_tag
+          if re.fullmatch(r"l\d+\.dwconv(\.bin)?", t)}
+    assert len(dw) == sum(op["op"] == "sepconv" for op in model.ops)
+    under = {p[i - 1] for p in paths for i, part in enumerate(p)
+             if part == "taps" and i > 0}
+    assert under == dw, (under, dw)
+    assert "_grouped_shared_call" not in lowered.as_text()
+
+
 MESH_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=3"

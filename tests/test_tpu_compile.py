@@ -5,8 +5,9 @@ the Pallas launch) with ``interpret=False`` at a launch shape of secure
 CifarNet2 served at batch 32 — the tuples ``cost_model.model_cost(model,
 (32, 32, 32, 3)).kernel_requests()`` lists — for a described, not attached,
 v5e chip.  The compiled program must hold the Mosaic kernel
-(``tpu_custom_call``) and fit one chip's HBM.  Nothing runs, so these say
-nothing about results or times.
+(``tpu_custom_call``) and fit one chip's HBM.  One test compiles the whole
+served inline runner and counts its launches against that list.  Nothing
+runs, so these say nothing about results or times.
 """
 import jax
 import jax.numpy as jnp
@@ -67,11 +68,14 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-# every distinct launch shape of the 18 per weight mode
+# every distinct launch shape (9 launches with shared weights, 18 with
+# public ones)
 # (M, K, N): the pointwise convolutions of the three blocks, classifier head
 DENSE = [(32768, 16, 16), (8192, 16, 32), (8192, 32, 32), (2048, 32, 48),
          (2048, 48, 48), (32, 768, 10)]
-# (M, C): the depthwise convolutions (K = 3x3 taps, N = 1)
+# (M, C): the depthwise convolutions (K = 3x3 taps, N = 1); with shared
+# weights they launch no kernel, and the grouped shared kernel is compiled
+# at these shapes as library code
 GROUPED = [(32768, 3), (32768, 16), (8192, 16), (8192, 32), (2048, 32),
            (2048, 48)]
 
@@ -116,6 +120,33 @@ def test_bin_grouped_matmul_compiles(one_chip, m, c):
          _spec((c, k, n), jnp.uint32, one_chip),
          _spec((L_PUBLIC, c, k, n), jnp.int8, one_chip)])
 
+
+
+def test_cifarnet2_inline_runner_launches(one_chip, monkeypatch):
+    """The served CifarNet2 inline runner (shared weights, batch 32, the
+    kernel path) holds one Mosaic kernel per launch that
+    ``kernel_requests()`` lists, the dense ones only: its depthwise halves
+    are direct tap products, with no grouped launch."""
+    from repro.core import RING32, cost_model
+    from repro.core.randomness import Parties
+    from repro.kernels import lowering
+    from repro.launch.serve_secure import build, make_runner
+    from repro.nn.bnn import INPUT_SHAPES
+
+    # the CPU backend would pick interpret mode; compile the Mosaic kernels
+    monkeypatch.setattr(lowering, "default_interpret", lambda: False)
+    net, batch = "CifarNet2", 32
+    shape = (batch,) + INPUT_SHAPES[net]
+    model = build(net, True, "shared")
+    reqs = cost_model.model_cost(model, shape).kernel_requests()
+    run, _ = make_runner(model, "local", batch)
+    keys = Parties.setup(jax.random.PRNGKey(7)).keys
+    text = run.lower(_spec(keys.shape, keys.dtype, one_chip),
+                     _spec((S,) + shape, RING32.dtype, one_chip)
+                     ).compile().as_text()
+    assert reqs and {r[0] for r in reqs} == {"rss_matmul"}
+    assert "_grouped_shared_call" not in text
+    assert text.count(KERNEL_CALL) == len(reqs)
 
 
 def _dense_call(one_chip):

@@ -74,9 +74,11 @@ run_case("MnistNet3", (28, 28, 1), 2, True, True, mesh3, weights="public")
 # arithmetic opening on both backends
 run_case("MnistNet1", (28, 28, 1), 4, False, True, mesh3,
          binary_linear="off")
-# depthwise-separable net (§13): the grouped kernel takes the per-party
-# pair layout (own+next passed separately) — all three weight/engine modes
+# depthwise-separable net (§13): the direct depthwise tap product takes
+# the per-party pair's own/next views, and the public grouped kernel the
+# pair stack — all three weight/engine modes, kernel and jnp pointwise
 run_case("MnistNet3-sep", (28, 28, 1), 2, True, True, mesh3)
+run_case("MnistNet3-sep", (28, 28, 1), 2, False, True, mesh3)
 run_case("MnistNet3-sep", (28, 28, 1), 2, True, True, mesh3,
          weights="public")
 run_case("MnistNet3-sep", (28, 28, 1), 2, True, True, mesh3,
@@ -245,8 +247,8 @@ print("OK")
 
 def test_mesh_backend_bit_identical(tmp_path):
     """secure_infer under MeshTransport == LocalTransport, bit for bit,
-    on an fc net and conv nets, fused + paper rounds, kernel + jnp dots,
-    with and without a composed data axis."""
+    on an fc net, conv nets and a separable net, fused + paper rounds,
+    kernel + jnp dots, with and without a composed data axis."""
     run_party_subprocess(EQUIV_SCRIPT, tmp_path, "mesh_equiv.py")
 
 
