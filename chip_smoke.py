@@ -1,10 +1,11 @@
-"""Chip smoke: secure CifarNet2 serving and the secure LM decode, once, on a TPU.
+"""Chip smoke: secure CifarNet2/6 serving and the secure LM decode, once, on a TPU.
 
 Drives ``repro.launch.serve_secure``'s own entry points (``build`` /
 ``make_runner`` / ``make_tape_runner`` / ``serve_pool`` / ``main``) in one
-process, at CifarNet2's published width (32x32x3 input, the paper's
-binarized Fitnet with separable convolutions), with random weights made from
-a seed.  Default phases, all on one chip:
+process, at CifarNet2's and CifarNet6's published widths (32x32x3 input;
+the paper's binarized Fitnet with separable convolutions, and its
+binarized VGG16), with random weights made from a seed.  Default phases,
+all on one chip:
 
   (a) fail unless JAX's default device is a TPU;
   (b) CifarNet2, shared weights, inline material, local backend: the
@@ -17,7 +18,10 @@ a seed.  Default phases, all on one chip:
   (d) CifarNet2 shared under the tape pool (``--offline pool``),
       bit-identical to (b)'s runner fed the same tape's session keys;
   (e) ``--model lm --quick``: token-identical to the fp32 oracle with one
-      decode trace per bucket.
+      decode trace per bucket;
+  (f) CifarNet6, shared weights, inline material, local backend: as (b),
+      with weights on a unit-scale grid and logits equal to ``bnn_forward``'s
+      (its 13 dense 3x3 convolutions run im2col and the dense kernel).
 
 ``--mesh`` (a host with at least three chips) runs the party mesh and what
 it is compared with, and nothing else: CifarNet2 shared with one party per
@@ -42,6 +46,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 
 NET = "CifarNet2"
+DEEP_NET = "CifarNet6"
 # serving batch: the compiled CifarNet2 program needs 2.8 GB of one v5e
 # chip's 16 GB at batch 32 (shared weights, memory_analysis)
 BATCH = 32
@@ -83,7 +88,7 @@ def require_tpu():
     return dev
 
 
-def query(batch, seed=0):
+def query(batch, seed=0, net=NET):
     """serve_secure's query: ±0.5 pixels, secret-shared, and party keys."""
     import jax
     import numpy as np
@@ -91,18 +96,21 @@ def query(batch, seed=0):
     from repro.core.randomness import Parties
     from repro.nn.bnn import INPUT_SHAPES
     rng = np.random.default_rng(seed)
-    x = rng.integers(0, 2, (batch,) + INPUT_SHAPES[NET]).astype(
+    x = rng.integers(0, 2, (batch,) + INPUT_SHAPES[net]).astype(
         np.float32) - 0.5
     xs = share(x, jax.random.PRNGKey(seed + 3), RING32)
     return x, xs.shares, Parties.setup(jax.random.PRNGKey(seed + 7)).keys
 
 
-def grid_params(seed=0):
+def grid_params(seed=0, net=NET, unit=False):
     """Random weights on a 1/8 grid with identity BN, as in
     tests/test_secure_model.py: with ±0.5 pixels every pre-activation sits
     at least 1/256 from the Sign boundary, far outside the fixed-point
     noise, so the secure run and the fp32 forward make the same Sign
-    decisions."""
+    decisions.  ``unit``: each weight is its initial value over the
+    tensor's standard deviation, rounded and clipped to ±2, over 8 (the
+    benchmark's recipe); the fan-in scaled grid rounds every weight of a
+    wide layer to zero."""
     import jax
     import jax.numpy as jnp
     from repro.nn import bnn
@@ -115,21 +123,23 @@ def grid_params(seed=0):
             return jnp.zeros_like(p)
         if name.endswith("_g"):
             return jnp.ones_like(p)
+        if p.ndim > 1 and unit:
+            return jnp.clip(jnp.round(p / jnp.std(p)), -2, 2) / 8
         if p.ndim > 1:
             return jnp.round(p * 0.5 * 8) / 8
         return jnp.round(p * 8) / 8 + 1.0 / 256
 
     return jax.tree_util.tree_map_with_path(
-        quant, bnn.init_bnn(jax.random.PRNGKey(seed), NET))
+        quant, bnn.init_bnn(jax.random.PRNGKey(seed), net))
 
 
-def plaintext_logits(params, x):
+def plaintext_logits(params, x, net=NET):
     """fp32 forward of the weights the secure model shares."""
     import jax
     import numpy as np
     from repro.nn import bnn
     with jax.default_matmul_precision("highest"):
-        out, _ = bnn.bnn_forward(params, x, NET)
+        out, _ = bnn.bnn_forward(params, x, net)
     return np.asarray(out, np.float32)
 
 
@@ -148,8 +158,9 @@ def serve_queries(run, keys, xs, queries):
     return np.asarray(out), time.perf_counter() - t0
 
 
-def bnn_inline(weights, batch, queries):
-    """Phases (b)/(c): kernel runner vs jnp lowering vs fp32 forward.
+def bnn_inline(weights, batch, queries, net=NET):
+    """Phases (b)/(c)/(f): kernel runner vs jnp lowering vs fp32 forward
+    (CifarNet6: unit-grid weights, logits equal to the fp32 forward's).
     Returns the model, the kernel runner's compiled program and its
     inputs."""
     import numpy as np
@@ -157,11 +168,12 @@ def bnn_inline(weights, batch, queries):
     from repro.launch.serve_secure import build, make_runner
     from repro.nn.bnn import INPUT_SHAPES
 
-    x, xs, keys = query(batch)
-    params = grid_params()
-    model = build(NET, True, weights, params=params)
+    exact = net == DEEP_NET
+    x, xs, keys = query(batch, net=net)
+    params = grid_params(net=net, unit=exact)
+    model = build(net, True, weights, params=params)
     reqs = cost_model.model_cost(
-        model, (batch,) + INPUT_SHAPES[NET]).kernel_requests()
+        model, (batch,) + INPUT_SHAPES[net]).kernel_requests()
     run, _ = make_runner(model, "local", batch)
     compiled, csec = timed_compile(run, keys, xs)
     text = compiled.as_text()
@@ -175,7 +187,7 @@ def bnn_inline(weights, batch, queries):
     out, wall = serve_queries(compiled, keys, xs, queries)
     log(f"{weights}: {queries} queries of batch {batch} in {wall:.3f} s")
 
-    ref_run, _ = make_runner(build(NET, False, weights, params=params),
+    ref_run, _ = make_runner(build(net, False, weights, params=params),
                              "local", batch)
     ref_compiled, rsec = timed_compile(ref_run, keys, xs)
     ref = np.asarray(ref_compiled(keys, xs))
@@ -186,11 +198,14 @@ def bnn_inline(weights, batch, queries):
           f"kernel logits differ from the jnp lowering in "
           f"{int((out != ref).sum())} of {out.size} places")
 
-    err = np.abs(out - plaintext_logits(params, x))
-    log(f"{weights}: |secure - fp32| median {np.median(err):.3g} "
-        f"max {err.max():.3g} (bounds {MEDIAN_ERR} / {MAX_ERR})")
-    check(np.median(err) < MEDIAN_ERR and err.max() < MAX_ERR,
-          "secure logits outside the fixed-point bounds")
+    err = np.abs(out - plaintext_logits(params, x, net))
+    log(f"{net} {weights}: |secure - fp32| median {np.median(err):.3g} "
+        f"max {err.max():.3g}")
+    if exact:
+        check(err.max() == 0, "secure logits differ from the fp32 forward")
+    else:
+        check(np.median(err) < MEDIAN_ERR and err.max() < MAX_ERR,
+              "secure logits outside the fixed-point bounds")
     return model, compiled, keys, xs
 
 
@@ -299,6 +314,8 @@ def main(argv=None):
             bnn_pool(model, inline_run, keys, xs, BATCH, QUERIES)
         with phase("e (lm --quick)"):
             lm_quick("local")
+        with phase("f (CifarNet6 shared, inline, local)"):
+            bnn_inline("shared", BATCH, QUERIES, net=DEEP_NET)
     print(json.dumps({"ok": True, "device": dev}))
 
 

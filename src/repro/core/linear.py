@@ -379,11 +379,24 @@ def conv2d(x: RSS, w: RSS, parties: Parties, stride: int = 1,
 
 
 def _im2col_rss(x: RSS, kh, kw, stride, padding):
+    """Patch matrix of every share slot, under an ``im2col`` scope nested
+    in the caller's layer tag, so a trace shows the patch build apart
+    from the product and the reshare.
+
+    The patch matrix is materialized (``optimization_barrier``) before
+    its consumer runs.  Left free, the TPU compiler fuses the taps'
+    concatenation into the kernel operand's int8 limb decomposition, and
+    for a 3-channel input (CifarNet6's first convolution, K = 27) that
+    fusion reads the taps wrongly: on a v5e 5.6 M of its 50 M limbs came
+    out wrong, while the patch matrix alone and the kernel alone were
+    exact."""
     p = x.shares.shape[0]
     b, h, w, c = (int(d) for d in x.shape)
-    cols, ho, wo = _im2col(x.shares.reshape(p * b, h, w, c),
-                           kh, kw, stride, padding)
-    cols = cols.reshape((p, b) + cols.shape[1:])
+    with comm.scope("im2col"):
+        cols, ho, wo = _im2col(x.shares.reshape(p * b, h, w, c),
+                               kh, kw, stride, padding)
+        cols = jax.lax.optimization_barrier(
+            cols.reshape((p, b) + cols.shape[1:]))
     return RSS(cols, x.ring), ho, wo
 
 

@@ -1,3 +1,4 @@
+import dataclasses
 import os
 import signal
 import subprocess
@@ -65,3 +66,23 @@ def run_party_subprocess(script_text: str, tmp_path, name: str):
                        text=True, timeout=900, env=env, cwd=str(repo))
     assert r.returncode == 0 and "OK" in r.stdout, \
         f"stdout:\n{r.stdout[-3000:]}\nstderr:\n{r.stderr[-3000:]}"
+
+
+def with_array_arguments(model):
+    """``(arrays, run)``: the secure model's arrays, and ``run(keys,
+    x_stack, arrays)``, its inline program (``secure_infer``) taking them
+    as arguments.  Closed over, as the served runner holds them, they are
+    written into the lowered program as constants: large for CifarNet6."""
+    from repro.core.rss import RSS
+    from repro.core.secure_model import secure_infer
+    leaves, tree = jax.tree_util.tree_flatten(model.ops)
+    arrays = [v for v in leaves if isinstance(v, jax.Array)]
+
+    def run(keys, x_stack, arrs):
+        it = iter(arrs)
+        ops = jax.tree_util.tree_unflatten(
+            tree, [next(it) if isinstance(v, jax.Array) else v
+                   for v in leaves])
+        return secure_infer(dataclasses.replace(model, ops=ops),
+                            RSS(x_stack, model.ring), Parties(keys))
+    return arrays, run

@@ -4,6 +4,8 @@
   ops (``comm.scope``), with each protocol call's full tag nested inside
   its head, in the inline, tape and mesh online programs — the names by
   which a device trace is split into protocol layers.
+* Every dense convolution's patch build is an ``im2col`` scope inside
+  its layer tag.
 * ``telemetry.span`` writes a ``cbnn.<name>`` annotation into a profiler
   trace with no tracer installed, and records nothing then.
 * The tape pool's staging has its spans and counters.
@@ -13,7 +15,7 @@ import re
 import jax
 import pytest
 
-from repro.core import RING32, telemetry
+from repro.core import RING32, comm, telemetry
 from repro.core.preprocessing import (MaterialTape, TapePool,
                                       make_tape_generator, make_tape_infer,
                                       online_cost, tape_session_keys,
@@ -24,7 +26,7 @@ from repro.core.secure_model import (compile_secure, secure_infer,
                                      secure_infer_cost)
 from repro.nn.bnn import INPUT_SHAPES, init_bnn
 
-from conftest import run_party_subprocess
+from conftest import run_party_subprocess, with_array_arguments
 
 TAG = re.compile(r"^((?:l|sign|relu|aff|mp)\d+)\.")
 
@@ -104,6 +106,37 @@ def test_depthwise_product_runs_under_taps(binary_linear):
              if part == "taps" and i > 0}
     assert under == dw, (under, dw)
     assert "_grouped_shared_call" not in lowered.as_text()
+
+
+def _bare_scope(tag):
+    """``comm.scope`` that names nothing."""
+    from contextlib import nullcontext
+    return nullcontext(tag)
+
+
+@pytest.mark.parametrize("net", ["CifarNet6", "CifarNet2"])
+def test_patch_build_runs_under_im2col(net, monkeypatch):
+    """Every dense convolution (CifarNet6's 3x3 layers, CifarNet2's
+    pointwise halves) builds its patch matrix inside an ``im2col`` scope
+    nested in its ledger tag (``l{i}.conv``, ``l{i}.conv.bin``,
+    ``l{i}.pwconv``), so a trace shows the patch build apart from the
+    product and the reshare; the scope leaves the ledger as it was."""
+    model = _model(net)
+    shape = (1,) + INPUT_SHAPES[net]
+    led = secure_infer_cost(model, shape)
+    arrays, run = with_array_arguments(model)
+    keys = Parties.setup(jax.random.PRNGKey(7)).keys
+    x = jax.ShapeDtypeStruct((3,) + shape, RING32.dtype)
+    paths = _scope_paths(jax.jit(run).lower(keys, x, arrays))
+    dense = {t for t in led.by_tag
+             if re.fullmatch(r"l\d+\.(conv|pwconv)(\.bin)?", t)}
+    assert len(dense) == sum(op["op"] in ("conv", "sepconv")
+                             for op in model.ops)
+    under = {p[i - 1] for p in paths for i, part in enumerate(p)
+             if part == "im2col" and i > 0}
+    assert under == dense, (under, dense)
+    monkeypatch.setattr(comm, "scope", _bare_scope)
+    assert dict(secure_infer_cost(model, shape).by_tag) == dict(led.by_tag)
 
 
 MESH_SCRIPT = r"""

@@ -2,13 +2,16 @@
 
 Each test compiles one kernel dispatcher (limb decomposition, padding and
 the Pallas launch) with ``interpret=False`` at a launch shape of secure
-CifarNet2 served at batch 32 — the tuples ``cost_model.model_cost(model,
-(32, 32, 32, 3)).kernel_requests()`` lists — for a described, not attached,
-v5e chip.  The compiled program must hold the Mosaic kernel
+CifarNet2 or CifarNet6 served at batch 32 — the tuples
+``cost_model.model_cost(model, (32, 32, 32, 3)).kernel_requests()`` lists —
+for a described, not attached, v5e chip.  The compiled program must hold the Mosaic kernel
 (``tpu_custom_call``) and fit one chip's HBM.  One test compiles the whole
-served inline runner and counts its launches against that list.  Nothing
+served CifarNet2 inline runner and counts its launches against that list;
+another lowers the CifarNet6 one and counts its kernel calls.  Nothing
 runs, so these say nothing about results or times.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -73,6 +76,12 @@ def _spec(shape, dtype, sharding):
 # (M, K, N): the pointwise convolutions of the three blocks, classifier head
 DENSE = [(32768, 16, 16), (8192, 16, 32), (8192, 32, 32), (2048, 32, 48),
          (2048, 48, 48), (32, 768, 10)]
+# CifarNet6 at batch 32 (16 launches either way): the thirteen 3x3
+# convolutions' im2col products (K = 9 Cin), the hidden FCs and the head
+DENSE_6 = [(32768, 27, 64), (32768, 576, 64), (8192, 576, 128),
+           (8192, 1152, 128), (2048, 1152, 256), (2048, 2304, 256),
+           (512, 2304, 512), (512, 4608, 512), (128, 4608, 512),
+           (32, 512, 512), (32, 512, 10)]
 # (M, C): the depthwise convolutions (K = 3x3 taps, N = 1); with shared
 # weights they launch no kernel, and the grouped shared kernel is compiled
 # at these shapes as library code
@@ -80,7 +89,7 @@ GROUPED = [(32768, 3), (32768, 16), (8192, 16), (8192, 32), (2048, 32),
            (2048, 48)]
 
 
-@pytest.mark.parametrize("m,k,n", DENSE)
+@pytest.mark.parametrize("m,k,n", DENSE + DENSE_6)
 def test_rss_matmul_compiles(one_chip, m, k, n):
     u32 = [_spec((S, k, n), jnp.uint32, one_chip)] * 2
     i8 = [_spec((S, 4, _tile(k), _tile(n)), jnp.int8, one_chip)] * 2
@@ -89,7 +98,7 @@ def test_rss_matmul_compiles(one_chip, m, k, n):
         [_spec((S, m, k), jnp.uint32, one_chip), *u32, *i8])
 
 
-@pytest.mark.parametrize("m,k,n", DENSE)
+@pytest.mark.parametrize("m,k,n", DENSE + DENSE_6)
 def test_bin_rss_matmul_compiles(one_chip, m, k, n):
     _compile_and_check(
         lambda x, w, wl: bin_rss_matmul_parts(
@@ -147,6 +156,62 @@ def test_cifarnet2_inline_runner_launches(one_chip, monkeypatch):
     assert reqs and {r[0] for r in reqs} == {"rss_matmul"}
     assert "_grouped_shared_call" not in text
     assert text.count(KERNEL_CALL) == len(reqs)
+
+
+def test_cifarnet6_inline_runner_launches(one_chip, monkeypatch):
+    """The served CifarNet6 inline runner (shared weights, batch 32, the
+    kernel path) calls the dense Mosaic kernel once per launch that
+    ``kernel_requests()`` lists: its thirteen 3x3 convolutions and three
+    FC layers, 16 (11 distinct shapes, one kernel function each).  The
+    runner's program is lowered for the chip, not compiled, with the
+    model's arrays as arguments: with its 0.9 GB of weight shares and
+    limbs as constants, the compile takes minutes and about 9 GB of host
+    memory here."""
+    from conftest import with_array_arguments
+    from repro.core import RING32, cost_model
+    from repro.core.randomness import Parties
+    from repro.kernels import lowering
+    from repro.launch.serve_secure import build
+    from repro.nn.bnn import INPUT_SHAPES
+
+    monkeypatch.setattr(lowering, "default_interpret", lambda: False)
+    net, batch = "CifarNet6", 32
+    shape = (batch,) + INPUT_SHAPES[net]
+    model = build(net, True, "shared")
+    reqs = cost_model.model_cost(model, shape).kernel_requests()
+    arrays, run = with_array_arguments(model)
+    keys = Parties.setup(jax.random.PRNGKey(7)).keys
+    text = jax.jit(run).lower(
+        _spec(keys.shape, keys.dtype, one_chip),
+        _spec((S,) + shape, RING32.dtype, one_chip),
+        [_spec(a.shape, a.dtype, one_chip) for a in arrays]).as_text()
+    calls = re.findall(r"call @(_rss_matmul_call[\w.]*)\(", text)
+    assert len(reqs) == 16 and {r[0] for r in reqs} == {"rss_matmul"}
+    assert len(calls) == len(reqs)
+    assert text.count("@tpu_custom_call(") == len(set(calls)) == 11
+
+
+def test_patch_matrix_is_materialized_before_its_limbs(one_chip):
+    """CifarNet6's first convolution (3 channels, K = 27): the compiled
+    product holds the patch matrix as a buffer of its own, so the
+    taps' concatenation is not fused into the kernel operand's limb
+    decomposition, the fusion the TPU compiler got wrong."""
+    from repro.core import RING32
+    from repro.core.linear import _im2col_rss
+    from repro.core.rss import RSS
+    m, k, n = DENSE_6[0]
+
+    def first_conv(x, *w):
+        cols = _im2col_rss(RSS(x, RING32), 3, 3, 1, 1)[0].shares
+        return rss_matmul_parts(cols.reshape(S, m, k), WeightLimbs(*w),
+                                interpret=False)
+    text = jax.jit(first_conv).lower(
+        _spec((S, 32, 32, 32, 3), jnp.uint32, one_chip),
+        *[_spec((S, k, n), jnp.uint32, one_chip)] * 2,
+        *[_spec((S, 4, _tile(k), _tile(n)), jnp.int8, one_chip)] * 2
+    ).compile().as_text()
+    assert KERNEL_CALL in text
+    assert re.search(r"= u32\[3,32,32,32,27\]", text)
 
 
 def _dense_call(one_chip):
