@@ -47,6 +47,7 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import comm, telemetry, transport
 from .integrity import (MaterialDesyncError, PoolExhaustedError,
@@ -57,8 +58,8 @@ from .rss import RSS, BinRSS, PARTIES
 
 __all__ = ["MaterialItem", "MaterialSpec", "MaterialTape", "TapeParties",
            "TapePool", "trace_material", "make_tape_generator",
-           "generate_tape", "tape_session_keys", "online_cost",
-           "STACK_PAIR", "STACK_PARTS", "REPLICATED"]
+           "make_tape_slicer", "generate_tape", "tape_session_keys",
+           "online_cost", "STACK_PAIR", "STACK_PARTS", "REPLICATED"]
 
 # slab layout classes (how a party-sliced consumer reads the slab)
 STACK_PAIR = "stack_pair"    # party-stacked; P_i consumes rows (i, i+1)
@@ -138,6 +139,7 @@ class MaterialSpec:
                 self.slabs[base + suffix] = SlabInfo(layout, sshape, dtype)
 
         self._gen = None   # cached jitted offline plant (make_tape_generator)
+        self._slice = None  # cached jitted slot slicer (make_tape_slicer)
 
     def __len__(self):
         return len(self.items)
@@ -307,6 +309,25 @@ def make_tape_generator(spec: MaterialSpec):
     return spec._gen
 
 
+def make_tape_slicer(spec: MaterialSpec):
+    """``slice(slabs, q) -> slabs at query slot q``: every slab of a tape
+    cut at a traced int32 slot in ONE jitted program (axis 1 of stacked
+    layouts, axis 0 of replicated ones), so staging a query is one
+    dispatch, and one executable serves every slot and buffer of a pool.
+    Cached on the spec, like :func:`make_tape_generator`."""
+    if spec._slice is not None:
+        return spec._slice
+
+    def cut(slabs, q):
+        return {k: jax.lax.dynamic_index_in_dim(
+                    v, q, axis=0 if spec.slabs[k].layout == REPLICATED
+                    else 1, keepdims=False)
+                for k, v in slabs.items()}
+
+    spec._slice = jax.jit(cut)
+    return spec._slice
+
+
 def tape_session_keys(session_key, n_queries: int):
     """(N, 3) fresh per-query party-key stacks from one session key."""
     return jax.vmap(lambda k: jax.random.split(k, PARTIES))(
@@ -322,15 +343,16 @@ class MaterialTape:
     n_queries: int
 
     def query_slice(self, q: int) -> dict:
-        """The per-query slab dict slot ``q`` (device slicing, async: each
-        slab is cut by small programs of its own, and
-        ``tape_slice_dispatches_total`` counts the slabs)."""
+        """The per-query slab dict of slot ``q`` (device slicing, async:
+        one program cuts every slab, :func:`make_tape_slicer`, and
+        ``tape_slice_dispatches_total`` counts it)."""
+        if not 0 <= q < self.n_queries:
+            raise IndexError(f"tape slot {q} outside a tape of "
+                             f"{self.n_queries} queries")
         with telemetry.span("tape_slice", cat="offline"):
-            sl = {k: (v[:, q] if self.spec.slabs[k].layout != REPLICATED
-                      else v[q])
-                  for k, v in self.slabs.items()}
+            sl = make_tape_slicer(self.spec)(self.slabs, np.int32(q))
         telemetry.inc("tape_slices_total")
-        telemetry.inc("tape_slice_dispatches_total", len(sl))
+        telemetry.inc("tape_slice_dispatches_total")
         return sl
 
     @property

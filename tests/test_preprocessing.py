@@ -77,6 +77,41 @@ def test_tape_bit_identical_local(net, kw):
         assert np.array_equal(ref, out), (net, kw, q)
 
 
+@pytest.mark.parametrize("net,fused", [
+    ("MnistNet1", False),   # paper rounds: pair, parts and replicated slabs
+    ("MnistNet3", True),    # conv net: 21 stacked slabs
+])
+def test_query_slice_one_program_equals_eager_indexing(net, fused):
+    """``query_slice(q)`` is eager per-slab indexing (``v[:, q]`` stacked,
+    ``v[q]`` replicated) bit for bit, in dtype and shape, for every slab
+    and slot, from one cached executable."""
+    from repro.core.linear import set_fused_rounds
+    model = _model(net)
+    set_fused_rounds(fused)
+    try:
+        spec = prep.trace_material(model, (2,) + INPUT_SHAPES[net])
+    finally:
+        set_fused_rounds(True)
+    layouts = {info.layout for info in spec.slabs.values()}
+    if not fused:
+        assert layouts == {prep.STACK_PAIR, prep.STACK_PARTS,
+                           prep.REPLICATED}
+    tape = prep.generate_tape(
+        spec, prep.tape_session_keys(jax.random.PRNGKey(9), 3))
+    for q in range(3):
+        sl = tape.query_slice(q)
+        assert set(sl) == set(spec.slabs)
+        for k, v in tape.slabs.items():
+            want = np.asarray(v[q] if spec.slabs[k].layout == prep.REPLICATED
+                              else v[:, q])
+            got = np.asarray(sl[k])
+            assert got.dtype == want.dtype and got.shape == want.shape, k
+            assert np.array_equal(got, want), (k, q)
+    assert prep.make_tape_slicer(spec)._cache_size() == 1
+    with pytest.raises(IndexError):
+        tape.query_slice(3)
+
+
 def test_online_ledger_matches_inline_online_rows():
     """The tape-backed program's ledger is exactly the inline ledger's
     online (non-``pre:``) rows — rounds, bytes, and per-tag."""
