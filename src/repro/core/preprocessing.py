@@ -43,11 +43,11 @@ like the inline PRF draws they replace.
 from __future__ import annotations
 
 import dataclasses
+import time
 import warnings
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from . import comm, telemetry, transport
 from .integrity import (MaterialDesyncError, PoolExhaustedError,
@@ -350,7 +350,7 @@ class MaterialTape:
             raise IndexError(f"tape slot {q} outside a tape of "
                              f"{self.n_queries} queries")
         with telemetry.span("tape_slice", cat="offline"):
-            sl = make_tape_slicer(self.spec)(self.slabs, np.int32(q))
+            sl = make_tape_slicer(self.spec)(self.slabs, q)
         telemetry.inc("tape_slices_total")
         telemetry.inc("tape_slice_dispatches_total")
         return sl
@@ -495,6 +495,21 @@ class TapeParties(Parties):
 # The pool: bounded, accounted, backpressured tape supply
 # ---------------------------------------------------------------------------
 
+# pause between readiness polls of a staged slice (seconds)
+_POLL_PAUSE_S = 5e-5
+
+
+def _poll_ready(slabs: dict):
+    """Wait until ``slabs`` are on the device by polling with short
+    pauses.  The online program is dispatched right after; a host thread
+    that slept through the refill and the slice in one long
+    ``block_until_ready`` dispatches and collects it measurably slower
+    (PERF.md §6), and a tight poll slows it more."""
+    for leaf in slabs.values():   # one program's outputs: ready together
+        while not leaf.is_ready():
+            time.sleep(_POLL_PAUSE_S)
+
+
 class TapePool:
     """Double-buffered supply of per-query tape slices with explicit
     accounting (DESIGN.md §14).
@@ -571,8 +586,9 @@ class TapePool:
         return self.generated * self.depth - self.taken
 
     def take(self) -> dict:
-        """The next per-query slab slice, dispatching the next refill as
-        a buffer drains (all of it the ``tape_take`` span).  Warns on
+        """The next per-query slab slice, on the device when it returns,
+        dispatching the next refill as a buffer drains (all of it the
+        ``tape_take`` span).  Warns on
         backpressure, raises :class:`PoolExhaustedError` when the budget
         is spent."""
         with telemetry.span("tape_take", cat="offline"):
@@ -614,6 +630,7 @@ class TapePool:
             sl = tape.query_slice(slot)
             if self.verify:
                 verify_tape_slice(self.spec, sl)
+            _poll_ready(sl)
             return sl
 
 

@@ -206,7 +206,8 @@ def test_tape_staging_spans_and_counters():
         # the first buffer dispatches a refill
         pool = TapePool(gen, spec, 2, jax.random.PRNGKey(5), demand=6)
         for _ in range(3):
-            pool.take()
+            # take returns once its slice is on the device
+            assert all(v.is_ready() for v in pool.take().values())
     names = [s.name for s in t.spans]
     assert names.count("tape_take") == 3 and names.count("tape_slice") == 3
     assert {"tape_refill[0]", "tape_refill[1]", "tape_refill[2]"} \
