@@ -134,35 +134,5 @@ def kernels():
     rows.append(("kernel.ssd_scan.512", _t(f5, xs, bm, cm, da, dt) * 1e6,
                  f"tpu_v5e_ideal_us={ssd_flops/197e12*1e6:.3f} "
                  f"chunk={qc} (intra-chunk MXU matrix form)"))
-    rows.extend(autotune_rows())
     return rows
 
-
-def autotune_rows():
-    """Measured autotuner wins (DESIGN.md §15): the fixed default config vs
-    the cache's best per launch, from the same persisted JSON the compiler
-    consults (`benchmarks/autotune_cache.json`).  On CPU the headline move
-    is lowering=ref (the interpreted Pallas grid loop loses to XLA by
-    orders of magnitude on grouped shapes); on TPU the same machinery
-    searches block shapes."""
-    from pathlib import Path
-
-    from repro.kernels import autotune
-    from repro.kernels.lowering import DEFAULT_CONFIG
-
-    cache = Path(__file__).resolve().parent / "autotune_cache.json"
-    rows = []
-    for family, m, k, n, n_limbs, ch in (
-            ("rss_matmul", 256, 256, 256, 4, None),
-            ("grouped_rss_matmul", 256, 9, 1, 4, 16)):
-        best, timings = autotune.autotune(
-            family, m, k, n, n_limbs=n_limbs, channels=ch, iters=2,
-            smoke=True, cache_path=cache)
-        best_us = timings[best]
-        default_us = timings.get(DEFAULT_CONFIG, best_us)
-        rows.append((f"kernel.autotune.{family}.{m}.default", default_us,
-                     f"cfg={DEFAULT_CONFIG.describe()}"))
-        rows.append((f"kernel.autotune.{family}.{m}.tuned", best_us,
-                     f"cfg={best.describe()} speedup_vs_default="
-                     f"{default_us / max(best_us, 1e-9):.2f}x"))
-    return rows

@@ -40,13 +40,7 @@ generation cost back in.
 ``secure.verify.<net>.local.b<batch>.{off,opens,full}`` rows time the
 integrity levels of DESIGN.md §14 on the same serving cell: CI pins
 ``opens`` within ~10% of the unverified ``off`` row, and this module
-asserts all three produce bit-identical logits.
-
-``secure.compiled.<net>.local.b<batch>.{default,tuned}`` rows time the
-cost-model-driven compile (DESIGN.md §15): ``tuned`` compiles against a
-deployment descriptor with the kernel autotuner's persisted cache
-(``benchmarks/autotune_cache.json``), pinning each matmul launch's
-measured-best `KernelConfig`; CI pins tuned strictly below default."""
+asserts all three produce bit-identical logits."""
 from __future__ import annotations
 
 import sys
@@ -69,9 +63,6 @@ VERIFY_CELLS = [("MnistNet3", 4)]
 # on the same cell as the secure.<net>.local.b<batch> baseline; CI pins
 # off within 5% of that untouched baseline and on within 15% of off
 OBS_CELLS = [("MnistNet3", 4)]
-# cost-model-compiled cells (DESIGN.md §15): fixed-default kernel configs
-# vs the autotuned compile (deployment descriptor + persisted kernel cache)
-COMPILED_CELLS = [("MnistNet1", 8)]
 COMM_NETS = ["MnistNet1", "MnistNet3"]
 QUERIES = 3
 
@@ -311,69 +302,6 @@ def _obs_rows(net: str, batch: int):
              f"{us_on / us_off:.2f}x vs off")]
 
 
-def _compiled_rows(net: str, batch: int):
-    """Cost-model-driven compile (DESIGN.md §15) vs the fixed defaults on
-    the SAME kernel-path serving cell: ``tuned`` compiles with a deployment
-    descriptor and the autotuner's persisted cache, so each matmul launch
-    runs its measured-best `KernelConfig` (on CPU that is the XLA ref
-    lowering — interpret-mode Pallas loses by a wide margin; on TPU the
-    searched block shapes).  Both lowerings are bit-exact mod 2^32, so the
-    outputs are asserted identical — the speedup is schedule, not math."""
-    from pathlib import Path
-
-    import numpy as np
-    import jax
-    from repro.core import RING32, cost_model, share
-    from repro.core.randomness import Parties
-    from repro.core.secure_model import compile_secure
-    from repro.kernels import autotune
-    from repro.launch.serve_secure import make_runner
-    from repro.nn import bnn
-    from repro.nn.bnn import INPUT_SHAPES
-
-    shape = INPUT_SHAPES[net]
-    cache = Path(__file__).resolve().parent / "autotune_cache.json"
-    params = bnn.init_bnn(jax.random.PRNGKey(0), net)
-    default_model = compile_secure(params, net, jax.random.PRNGKey(1),
-                                   RING32, use_kernel_dot=True)
-    # tune every launch this model performs (smoke space; the JSON cache
-    # persists, so reruns and the compiler itself hit it for free)
-    reqs = cost_model.model_cost(default_model,
-                                 (batch,) + shape).kernel_requests()
-    autotune.ensure_tuned(reqs, iters=1, smoke=True, cache_path=cache)
-    tuned_model = compile_secure(params, net, jax.random.PRNGKey(1),
-                                 RING32, use_kernel_dot=True,
-                                 deployment=cost_model.LAN.with_batch(batch),
-                                 autotune_cache=cache)
-
-    rng = np.random.default_rng(0)
-    x = (rng.integers(0, 2, (batch,) + shape).astype(np.float32) - 0.5)
-    xs = share(x, jax.random.PRNGKey(3), RING32)
-    keys = Parties.setup(jax.random.PRNGKey(7)).keys
-
-    def timed(model):
-        run, _ = make_runner(model, "local", batch)
-        out = np.asarray(run(keys, xs.shares))  # compile + warm
-        best = float("inf")
-        for _ in range(QUERIES):
-            t0 = time.perf_counter()
-            np.asarray(run(keys, xs.shares))
-            best = min(best, time.perf_counter() - t0)
-        return best * 1e6, out
-
-    us_default, out_default = timed(default_model)
-    us_tuned, out_tuned = timed(tuned_model)
-    assert np.array_equal(out_default, out_tuned), \
-        "autotuned lowering must be bit-identical to the default"
-    kcfgs = [c.describe() for op in tuned_model.ops
-             for c in op.get("kcfg", []) if c is not None]
-    return [(f"secure.compiled.{net}.local.b{batch}.default", us_default,
-             "fixed 128-cube kernel config, platform-default lowering"),
-            (f"secure.compiled.{net}.local.b{batch}.tuned", us_tuned,
-             f"autotuned kcfg per launch [{', '.join(sorted(set(kcfgs)))}]; "
-             f"speedup_vs_default={us_default / max(us_tuned, 1e-9):.2f}x")]
-
-
 def _comm_rows(net: str):
     """Per-query online wire KB per deployment mode (batch 1) — the
     binary-domain byte trajectory, machine-readable in the JSON."""
@@ -417,8 +345,6 @@ def secure_e2e():
         rows.extend(_verify_rows(net, batch))
     for net, batch in OBS_CELLS:
         rows.extend(_obs_rows(net, batch))
-    for net, batch in COMPILED_CELLS:
-        rows.extend(_compiled_rows(net, batch))
     for net in COMM_NETS:
         rows.extend(_comm_rows(net))
     # secure LM serving rows (DESIGN.md §16): measured decode/prefill

@@ -24,11 +24,6 @@ solver minimizes (bytes, rounds, flops) lexicographically — which
 reproduces the historical fixed preference order exactly, so existing
 path labels (and the tests pinning them) are unchanged.
 
-The same compile step consults the kernel autotuner's persisted cache
-(`kernels.autotune`) and attaches the winning `KernelConfig` per matmul
-launch as ``op["kcfg"]`` — protocol path and kernel schedule are chosen
-together, at model-setup time, from measured data.
-
 All formulas below are in *ring elements*; wire bytes multiply by
 ``ring.nbytes``.  ``n`` is the layer's output numel including batch.
 The per-primitive table (verified against core/{linear,msb,activation,
@@ -176,8 +171,8 @@ class CostReport:
                                or LAN)
 
     def kernel_requests(self) -> list:
-        """All (family, m, k, n, n_limbs, channels) launches this model
-        performs — the exact tuples `kernels.autotune.ensure_tuned` takes."""
+        """All (family, m, k, n, n_limbs, channels) kernel launches this
+        model performs."""
         return [r for e in self.entries for r in e.requests]
 
     def within_offline_budget(self, dep=None) -> bool | None:
@@ -343,27 +338,8 @@ def _linear_requests(op: dict, m: int, kk: int, nn: int, *,
     return reqs
 
 
-def _lookup_kcfgs(op: dict, reqs: list, cache_path=None) -> list | None:
-    """Autotune-cache lookups aligned with the op's weight parts (sepconv:
-    [depthwise, pointwise]); None when nothing is cached."""
-    from ..kernels import autotune
-    by_family = {}
-    for fam, m, kk, nn, limbs, ch in reqs:
-        by_family[fam] = autotune.lookup(fam, m, kk, nn, n_limbs=limbs,
-                                         channels=ch, path=cache_path)
-    if op["op"] == "sepconv":
-        kcfg = [by_family.get("bin_grouped_matmul"),
-                by_family.get("bin_rss_matmul")
-                or by_family.get("rss_matmul")]
-    else:
-        kcfg = [by_family.get("bin_rss_matmul")
-                or by_family.get("rss_matmul")]
-    return kcfg if any(c is not None for c in kcfg) else None
-
-
 def model_cost(model, input_shape=None, *, deployment=None,
-               fused: bool | None = None, stamp: bool = False,
-               autotune_cache=None) -> CostReport:
+               fused: bool | None = None, stamp: bool = False) -> CostReport:
     """Walk a compiled `SecureModel` symbolically and return its predicted
     cost — byte-exact against the live `CommLedger` (tests/test_cost_model).
 
@@ -372,7 +348,7 @@ def model_cost(model, input_shape=None, *, deployment=None,
     the applicable §11 paths, argmins them under ``deployment`` (or
     lexicographic (bytes, rounds, flops) when None — the historical fixed
     preference order), and with ``stamp=True`` writes the decision back
-    onto the op (``path`` / ``engine`` / ``cost`` / ``kcfg``).  ``fused``
+    onto the op (``path`` / ``engine`` / ``cost``).  ``fused``
     defaults to the active `set_fused_rounds` state."""
     dep = resolve_deployment(deployment)
     if fused is None:
@@ -419,10 +395,6 @@ def model_cost(model, input_shape=None, *, deployment=None,
                               "alternatives": {
                                   str(l): [c.rounds, c.nbytes]
                                   for l, _, c in cands}}
-                if model.use_kernel:
-                    kcfg = _lookup_kcfgs(op, reqs, cache_path=autotune_cache)
-                    if kcfg is not None:
-                        op["kcfg"] = kcfg
             cout = _w_shapes(op)[-1][-1]
             shape = ((shape[0], cout) if kind == "fc"
                      else (shape[0],) + spatial + (cout,))
@@ -476,12 +448,11 @@ def model_cost(model, input_shape=None, *, deployment=None,
 
 
 def annotate_model(model, input_shape=None, *, deployment=None,
-                   fused: bool | None = None,
-                   autotune_cache=None) -> CostReport:
+                   fused: bool | None = None) -> CostReport:
     """`model_cost` with ``stamp=True``: the compile-time entry point that
-    writes the solved path / engine / cost / kernel config onto each op."""
+    writes the solved path / engine / cost onto each op."""
     return model_cost(model, input_shape, deployment=deployment, fused=fused,
-                      stamp=True, autotune_cache=autotune_cache)
+                      stamp=True)
 
 
 # ---------------------------------------------------------------------------

@@ -145,21 +145,18 @@ def _ring_dot(a, b, ring: RingSpec):
         preferred_element_type=ring.dtype)
 
 
-def _matmul_parts(x: RSS, w: RSS | None, dot, w_limbs,
-                  kcfg=None) -> jax.Array:
+def _matmul_parts(x: RSS, w: RSS | None, dot, w_limbs) -> jax.Array:
     """Additive product stack z_i (parts layout) — local compute, no comm.
 
     With ``w_limbs`` (a kernels.rss_matmul.WeightLimbs cached at model
     setup) the whole 3-party product runs in ONE fused Pallas launch:
     activations are limb-decomposed once per share slab, weight limbs
-    (including the fused operand w_i + w_{i+1}) come precomputed.
-    ``kcfg`` (an autotuned `kernels.lowering.KernelConfig`, attached by
-    `compile_secure`) selects that launch's block sizes / lowering."""
+    (including the fused operand w_i + w_{i+1}) come precomputed."""
     t = transport.current()
     if w_limbs is not None:
         from ..kernels.ops import rss_matmul_parts_op
         return rss_matmul_parts_op(t.own_view(x.shares),
-                                   t.next_view(x.shares), w_limbs, cfg=kcfg)
+                                   t.next_view(x.shares), w_limbs)
     dot = dot or (lambda a, b: _ring_dot(a, b, x.ring))
     xo, wo = t.own_view(x.shares), t.own_view(w.shares)
     xn, wn = t.next_view(x.shares), t.next_view(w.shares)
@@ -174,7 +171,7 @@ def _matmul_parts(x: RSS, w: RSS | None, dot, w_limbs,
 
 
 def matmul(x: RSS, w: RSS | None, parties: Parties, tag: str = "matmul",
-           dot=None, w_limbs=None, kcfg=None) -> RSS:
+           dot=None, w_limbs=None) -> RSS:
     """Secure matmul  z = x @ w  (x: (..., K), w: (K, N)).
 
     ``dot`` may be swapped for the Pallas ring-matmul kernel
@@ -182,7 +179,7 @@ def matmul(x: RSS, w: RSS | None, parties: Parties, tag: str = "matmul",
     mod 2^l.  ``w_limbs`` routes through the fused 3-party kernel with
     cached weight limbs instead (w may then be None).
     """
-    z = _matmul_parts(x, w, dot, w_limbs, kcfg)
+    z = _matmul_parts(x, w, dot, w_limbs)
     return _reshare(z, x.ring, parties, tag)
 
 
@@ -210,7 +207,7 @@ def mul_open(x: RSS, y: RSS, parties: Parties, tag: str = "mul_open"):
 
 def matmul_truncate(x: RSS, w: RSS | None, parties: Parties,
                     tag: str = "matmul_tr", dot=None, w_limbs=None,
-                    bias_parts=None, kcfg=None) -> RSS:
+                    bias_parts=None) -> RSS:
     """Fused Alg-2 matmul + Π_trunc in ONE online round (beyond-paper).
 
     The reshare round already moves one ring element per output slot; the
@@ -226,7 +223,7 @@ def matmul_truncate(x: RSS, w: RSS | None, parties: Parties,
     3-party Pallas kernel with cached weight limbs.
     """
     ring = x.ring
-    z = _matmul_parts(x, w, dot, w_limbs, kcfg)
+    z = _matmul_parts(x, w, dot, w_limbs)
     if bias_parts is not None:
         z = z + bias_parts
     return _open_shift(z, parties, ring, ring.frac, tag)
@@ -358,22 +355,21 @@ def _grouped_conv_parts(x: RSS, w: RSS, stride: int, padding: int,
 
 def conv2d(x: RSS, w: RSS, parties: Parties, stride: int = 1,
            padding: int = 0, groups: int = 1, tag: str = "conv",
-           w_limbs=None, kcfg=None) -> RSS:
+           w_limbs=None) -> RSS:
     """Secure 2-D convolution. x: (B,H,W,Cin), w: (kh,kw,Cin/groups,Cout).
 
     ``w_limbs`` holds the setup-time limb cache of the (kh·kw·Cin, Cout)
     weight matrix (a `kernels.rss_matmul.WeightLimbs`, groups == 1): the
     im2col patches run through the fused 3-party kernel.  The depthwise
     case (groups == Cin) is the direct tap product of
-    `_grouped_conv_parts`, which needs no cache (``w_limbs`` and ``kcfg``
-    are ignored there).  Depthwise costs one reshare round for the whole
+    `_grouped_conv_parts`, which needs no cache (``w_limbs`` is ignored
+    there).  Depthwise costs one reshare round for the whole
     layer, same as dense."""
     kh, kw, cin_g, cout = (int(d) for d in w.shape)
     if groups == 1:
         cols, ho, wo = _im2col_rss(x, kh, kw, stride, padding)
         wmat = w.reshape(kh * kw * cin_g, cout)
-        return matmul(cols, wmat, parties, tag=tag, w_limbs=w_limbs,
-                      kcfg=kcfg)
+        return matmul(cols, wmat, parties, tag=tag, w_limbs=w_limbs)
     z = _grouped_conv_parts(x, w, stride, padding, groups)
     return _reshare(z, x.ring, parties, tag=tag)
 
@@ -402,14 +398,14 @@ def _im2col_rss(x: RSS, kh, kw, stride, padding):
 
 def conv2d_truncate(x: RSS, w: RSS, parties: Parties, stride: int = 1,
                     padding: int = 0, tag: str = "conv_tr", w_limbs=None,
-                    bias_parts=None, kcfg=None) -> RSS:
+                    bias_parts=None) -> RSS:
     """Fused conv (groups=1) + bias + Π_trunc, one online round: im2col then
     `matmul_truncate`."""
     kh, kw, cin_g, cout = (int(d) for d in w.shape)
     cols, ho, wo = _im2col_rss(x, kh, kw, stride, padding)
     wmat = w.reshape(kh * kw * cin_g, cout)
     return matmul_truncate(cols, wmat, parties, tag=tag, w_limbs=w_limbs,
-                           bias_parts=bias_parts, kcfg=kcfg)
+                           bias_parts=bias_parts)
 
 
 # ---------------------------------------------------------------------------
@@ -445,7 +441,7 @@ class PublicTensor:
 
 def bin_matmul(x: RSS, w: RSS | PublicTensor, parties: Parties,
                tag: str = "bin_matmul", dot=None, w_limbs=None,
-               bias_parts=None, bias_public=None, kcfg=None) -> RSS:
+               bias_parts=None, bias_public=None) -> RSS:
     """Binary-domain secure matmul: x holds post-Sign ±1 activations at
     scale 0, so z = x @ w already sits at the weights' scale f — no
     truncation opening ever rides this layer (DESIGN.md §11).
@@ -470,7 +466,7 @@ def bin_matmul(x: RSS, w: RSS | PublicTensor, parties: Parties,
         comm.record(tag, rounds=0, nbytes=0)
         wl = w.limbs if w_limbs is None else w_limbs
         if wl is not None:
-            z = bin_rss_matmul_op(x.shares, wl, cfg=kcfg)
+            z = bin_rss_matmul_op(x.shares, wl)
         else:
             d = dot or (lambda a, b: _ring_dot(a, b, x.ring))
             z = jnp.stack([d(x.shares[i], w.enc)
@@ -481,7 +477,7 @@ def bin_matmul(x: RSS, w: RSS | PublicTensor, parties: Parties,
         return out
     assert bias_public is None, \
         "shared weights take additive bias_parts, not a public encoding"
-    z = _matmul_parts(x, w, dot, w_limbs, kcfg)
+    z = _matmul_parts(x, w, dot, w_limbs)
     if bias_parts is not None:
         z = z + bias_parts
     return _reshare(z, x.ring, parties, tag)
@@ -490,13 +486,13 @@ def bin_matmul(x: RSS, w: RSS | PublicTensor, parties: Parties,
 def bin_conv2d(x: RSS, w: RSS | PublicTensor, parties: Parties,
                stride: int = 1, padding: int = 0, groups: int = 1,
                tag: str = "bin_conv", w_limbs=None, bias_parts=None,
-               bias_public=None, kcfg=None) -> RSS:
+               bias_public=None) -> RSS:
     """Binary-domain secure conv: im2col + `bin_matmul` (groups == 1) or the
     per-channel grouped contraction (groups == Cin, the depthwise half of a
     sepconv) — either way the post-Sign layer costs one reshare round
     (shared weights) or nothing at all (public weights).  Shared grouped
     convs are the direct tap product of `_grouped_conv_parts` (``w_limbs``
-    and ``kcfg`` are ignored there).  Public grouped convs run locally on
+    is ignored there).  Public grouped convs run locally on
     every held slot, through the grouped public-limb kernel when
     ``w.limbs`` carries a `PublicGroupedLimbs` cache."""
     if isinstance(w, PublicTensor):
@@ -508,7 +504,7 @@ def bin_conv2d(x: RSS, w: RSS | PublicTensor, parties: Parties,
             cols, ho, wo = _im2col_rss(x, kh, kw, stride, padding)
             wmat = PublicTensor(w.enc.reshape(kh * kw * cin_g, cout), w.limbs)
             return bin_matmul(cols, wmat, parties, tag=tag,
-                              bias_public=bias_public, kcfg=kcfg)
+                              bias_public=bias_public)
         # depthwise: per-channel contraction against the public kernel,
         # on every slot at once — still zero communication
         b = int(x.shape[0])
@@ -521,7 +517,7 @@ def bin_conv2d(x: RSS, w: RSS | PublicTensor, parties: Parties,
         comm.record(tag, rounds=0, nbytes=0)
         if w.limbs is not None:
             from ..kernels.ops import bin_grouped_matmul_op
-            z = bin_grouped_matmul_op(cols5, w.limbs, cfg=kcfg)
+            z = bin_grouped_matmul_op(cols5, w.limbs)
         else:
             wk = w.enc.reshape(kh * kw, cin, mult)
             z = jnp.einsum("sbhwkc,kcm->sbhwcm", cols5, wk,
@@ -545,7 +541,7 @@ def bin_conv2d(x: RSS, w: RSS | PublicTensor, parties: Parties,
     cols, ho, wo = _im2col_rss(x, kh, kw, stride, padding)
     wmat = w.reshape(kh * kw * cin_g, cout)
     return bin_matmul(cols, wmat, parties, tag=tag, w_limbs=w_limbs,
-                      bias_parts=bias_parts, kcfg=kcfg)
+                      bias_parts=bias_parts)
 
 
 # ---------------------------------------------------------------------------

@@ -85,8 +85,7 @@ def compile_secure(params: dict, net: str, key,
                    use_kernel_dot: bool = False,
                    weights: str = "shared",
                    binary_linear: str = "auto",
-                   deployment=None,
-                   autotune_cache=None) -> SecureModel:
+                   deployment=None) -> SecureModel:
     """Model-owner setup: fuse + share (or publish).  `params` are the
     trained plaintext parameters (bnn.py layout).
 
@@ -113,11 +112,7 @@ def compile_secure(params: dict, net: str, key,
     preference order to the symbolic cost solver: each linear layer gets
     the path minimizing predicted time under that link/compute model, and
     the per-layer prediction rides on the op as ``op["cost"]`` (the whole
-    report on ``model.predicted``).  With ``use_kernel_dot=True`` the
-    solver additionally consults the kernel autotuner's persisted cache
-    (``autotune_cache`` path or the default) and pins the measured-best
-    `KernelConfig` per launch as ``op["kcfg"]`` — both lowerings are
-    bit-exact mod 2^32, so this changes time only, never values."""
+    report on ``model.predicted``)."""
     assert weights in WEIGHT_MODES, weights
     assert binary_linear in BINARY_LINEAR_MODES, binary_linear
     # "generic" is the bit-identity reference for the bin-SHARED engine;
@@ -210,10 +205,8 @@ def compile_secure(params: dict, net: str, key,
                         deployment=dep.name if dep else None)
     # the symbolic solver re-derives every op's path label (ties keep the
     # fixed preference order, so deployment=None reproduces the legacy
-    # labels exactly), stamps per-layer predicted costs, and pins cached
-    # autotuned kernel configs when the kernel path is on
-    model.predicted = cost_model.annotate_model(model, deployment=dep,
-                                                autotune_cache=autotune_cache)
+    # labels exactly) and stamps per-layer predicted costs
+    model.predicted = cost_model.annotate_model(model, deployment=dep)
     return model
 
 
@@ -321,7 +314,6 @@ def _infer_linear_shared(h: RSS, op: dict, parties: Parties, idx: int,
     bit-identical to the bin-shared path, kept as its reference."""
     tp = transport.current()
     wlimbs = op.get("wlimbs") or [None] * len(op["w"])
-    kcfgs = op.get("kcfg") or [None] * len(op["w"])
     kind = op["op"]
     if kind == "sepconv":
         # separable: depthwise then pointwise (Alg 2 twice, Fig 3), the
@@ -334,20 +326,20 @@ def _infer_linear_shared(h: RSS, op: dict, parties: Parties, idx: int,
             with comm.scope(f"l{idx}.dwconv.bin") as tag:
                 h = bin_conv2d(h, op["w"][0], parties, stride=op["stride"],
                                padding=op["pad"], groups=cin, tag=tag,
-                               w_limbs=wlimbs[0], kcfg=kcfgs[0])
+                               w_limbs=wlimbs[0])
         else:
             with comm.scope(f"l{idx}.dwconv") as tag:
                 h = conv2d(h, op["w"][0], parties, stride=op["stride"],
                            padding=op["pad"], groups=cin, tag=tag,
-                           w_limbs=wlimbs[0], kcfg=kcfgs[0])
+                           w_limbs=wlimbs[0])
             if not binary_in:
                 with comm.scope(f"l{idx}.dwtrunc") as tag:
                     h = truncate(h, parties, tag=tag)
         at_2f = True
-        lin, w_rss, wl, kc = "pw", op["w"][1], wlimbs[1], kcfgs[1]
+        lin, w_rss, wl = "pw", op["w"][1], wlimbs[1]
     else:
         at_2f = not binary_in
-        lin, w_rss, wl, kc = kind, op["w"][0], wlimbs[0], kcfgs[0]
+        lin, w_rss, wl = kind, op["w"][0], wlimbs[0]
     if not at_2f and binary_engine:
         # bin-shared engine: scale-f bias rides the additive parts through
         # the single reshare round — 3 ring elements per output slot
@@ -356,10 +348,10 @@ def _infer_linear_shared(h: RSS, op: dict, parties: Parties, idx: int,
         with comm.scope(f"l{idx}.{lin}.bin") as tag:
             if lin == "fc":
                 return bin_matmul(h, w_rss, parties, tag=tag, w_limbs=wl,
-                                  bias_parts=bias, kcfg=kc)
+                                  bias_parts=bias)
             return bin_conv2d(h, w_rss, parties, stride=op["stride"],
                               padding=op["pad"], tag=tag, w_limbs=wl,
-                              bias_parts=bias, kcfg=kc)
+                              bias_parts=bias)
     if at_2f and fused_rounds():
         # beyond-paper default: product + bias + Π_trunc in the one
         # reshare round (matmul_truncate / conv2d_truncate) — the
@@ -370,22 +362,21 @@ def _infer_linear_shared(h: RSS, op: dict, parties: Parties, idx: int,
         with comm.scope(f"l{idx}.{_LIN_TAG[lin]}") as tag:
             if lin == "fc":
                 return matmul_truncate(h, w_rss, parties, tag=tag,
-                                       w_limbs=wl, bias_parts=bias, kcfg=kc)
+                                       w_limbs=wl, bias_parts=bias)
             if lin == "conv":
                 return conv2d_truncate(h, w_rss, parties,
                                        stride=op["stride"], padding=op["pad"],
-                                       tag=tag, w_limbs=wl, bias_parts=bias,
-                                       kcfg=kc)
+                                       tag=tag, w_limbs=wl, bias_parts=bias)
             return conv2d_truncate(h, w_rss, parties, tag=tag, w_limbs=wl,
-                                   bias_parts=bias, kcfg=kc)
+                                   bias_parts=bias)
     with comm.scope(f"l{idx}.{_LIN_TAG[lin]}") as tag:
         if lin == "fc":
-            z = matmul(h, w_rss, parties, tag=tag, w_limbs=wl, kcfg=kc)
+            z = matmul(h, w_rss, parties, tag=tag, w_limbs=wl)
         elif lin == "conv":
             z = conv2d(h, w_rss, parties, stride=op["stride"],
-                       padding=op["pad"], tag=tag, w_limbs=wl, kcfg=kc)
+                       padding=op["pad"], tag=tag, w_limbs=wl)
         else:
-            z = conv2d(h, w_rss, parties, tag=tag, w_limbs=wl, kcfg=kc)
+            z = conv2d(h, w_rss, parties, tag=tag, w_limbs=wl)
     # z is a full RSS here, so the bias is added share-wise
     bias = op["b"].shares.reshape(
         (z.shares.shape[0],) + (1,) * (z.ndim - 1) + (-1,))
@@ -409,32 +400,28 @@ def _infer_linear_public(h: RSS, op: dict, parties: Parties, idx: int,
     kind = op["op"]
     lift = jnp.asarray(ring.frac, ring.dtype)
     pub_b = jnp.asarray(op["pub_b"])
-    kcfgs = op.get("kcfg") or [None] * len(op["pub_w"])
     if kind == "sepconv":
         cin = int(h.shape[-1])
         with comm.scope(f"l{idx}.dwconv.pub") as tag:
             h = bin_conv2d(h, op["pub_w"][0], parties, stride=op["stride"],
-                           padding=op["pad"], groups=cin, tag=tag,
-                           kcfg=kcfgs[0])
+                           padding=op["pad"], groups=cin, tag=tag)
         if not binary_in:
             with comm.scope(f"l{idx}.dwtrunc") as tag:
                 h = truncate(h, parties, tag=tag)
         # pointwise input carries scale f, so the product lands at 2f
         with comm.scope(f"l{idx}.pwconv.pub") as tag:
             h = bin_conv2d(h, op["pub_w"][1], parties, tag=tag,
-                           bias_public=pub_b << lift, kcfg=kcfgs[1])
+                           bias_public=pub_b << lift)
         with comm.scope(f"l{idx}.trunc") as tag:
             return truncate(h, parties, tag=tag)
     w = op["pub_w"][0]
     bias = pub_b if binary_in else pub_b << lift
     with comm.scope(f"l{idx}.{kind}.pub") as tag:
         if kind == "fc":
-            h = bin_matmul(h, w, parties, tag=tag, bias_public=bias,
-                           kcfg=kcfgs[0])
+            h = bin_matmul(h, w, parties, tag=tag, bias_public=bias)
         else:
             h = bin_conv2d(h, w, parties, stride=op["stride"],
-                           padding=op["pad"], tag=tag, bias_public=bias,
-                           kcfg=kcfgs[0])
+                           padding=op["pad"], tag=tag, bias_public=bias)
     if not binary_in:
         with comm.scope(f"l{idx}.trunc") as tag:
             h = truncate(h, parties, tag=tag)

@@ -46,15 +46,18 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from .limbs import N_LIMBS, balanced_limbs
-from .lowering import KernelConfig, LOWERING_REF, resolve_interpret
+from .lowering import resolve_interpret
 
 __all__ = ["PublicWeightLimbs", "public_weight_limbs", "bin_rss_matmul",
            "bin_rss_matmul_ref", "bin_rss_matmul_parts",
-           "GroupedWeightLimbs", "grouped_weight_limbs",
            "PublicGroupedLimbs", "public_grouped_limbs",
-           "grouped_rss_matmul_parts", "bin_grouped_matmul_parts"]
+           "bin_grouped_matmul_parts"]
 
 _TILE = 128
+# Block shapes of every launch: one MXU tile on each axis of the dense
+# kernel, one tile of M for the grouped one.  No shape has been tuned on
+# the chip yet.
+_BM = _BN = _BK = _TILE
 
 
 class PublicWeightLimbs(typing.NamedTuple):
@@ -184,7 +187,7 @@ def _bin_rss_matmul_call(xl, wl, *, bm, bn, bk, interpret):
 
 
 def bin_rss_matmul(x_stack: jax.Array, weights: PublicWeightLimbs, *,
-                   bm: int = 128, bn: int = 128, bk: int = 128,
+                   bm: int = _BM, bn: int = _BN, bk: int = _BK,
                    interpret: bool | None = None) -> jax.Array:
     """Every held share slot's local product with a public weight matrix.
 
@@ -217,84 +220,32 @@ def bin_rss_matmul_ref(x_stack: jax.Array,
 
 def bin_rss_matmul_parts(x_stack: jax.Array, weights: PublicWeightLimbs, *,
                          min_dim: int = 8,
-                         interpret: bool | None = None,
-                         cfg: KernelConfig | None = None) -> jax.Array:
+                         interpret: bool | None = None) -> jax.Array:
     """Kernel dispatch with the small-shape fallback used across kernels/:
-    both paths are exact mod 2^32, so results are bit-identical.
-
-    ``cfg`` (an autotuned `KernelConfig`) overrides the fixed defaults:
-    ``lowering="ref"`` forces the XLA reference path, otherwise its block
-    sizes replace the 128-cube default."""
+    both paths are exact mod 2^32, so results are bit-identical."""
     _, m, k = x_stack.shape
-    if cfg is not None and cfg.lowering == LOWERING_REF:
-        return bin_rss_matmul_ref(x_stack, weights)
     if min(m, k, weights.n) < min_dim:
         return bin_rss_matmul_ref(x_stack, weights)
-    bm, bn, bk = (cfg.bm, cfg.bn, cfg.bk) if cfg is not None else (128, 128, 128)
-    return bin_rss_matmul(x_stack, weights, bm=bm, bn=bn, bk=bk,
-                          interpret=interpret)
+    return bin_rss_matmul(x_stack, weights, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
-# Grouped (depthwise) variants — the per-channel matmul family (DESIGN.md
-# §11/§13)
+# Grouped (depthwise) public-weight variant (DESIGN.md §11/§13)
 # ---------------------------------------------------------------------------
 #
 # A depthwise conv is a *grouped* matmul: channel c contracts its own
-# (M, K=kh·kw) patch matrix against its own tiny (K, mult) kernel.  Under
-# RSS this is far cheaper than a dense conv — the contraction depth is kh·kw
-# instead of kh·kw·Cin.  The two kernels below run it on the
-# limb-decomposed path of the dense kernels:
-#
-#   * `grouped_rss_matmul_parts` — SHARED weights: the fused-operand Alg-2
-#     additive products  z_i[c] = x_i[c]·(w_i[c]+w_{i+1}[c]) + x_{i+1}[c]·w_i[c]
-#     per channel, full 4×4 limb grid (both operands are shares).  Library
-#     code: the served path computes this product as a direct tap
-#     multiply-accumulate (`core.linear._grouped_conv_parts`), which needs
-#     no patch copies, limbs or launch.
-#   * `bin_grouped_matmul_parts` — PUBLIC weights: every held slot's local
-#     product z_s[c] = x_s[c] @ W[c], with the same adaptive limb collapse
-#     as the dense public kernel (L = 1..4 from the bounded encoding).
+# (M, K=kh·kw) patch matrix against its own tiny (K, mult) kernel.  With
+# PUBLIC weights every held slot's local product z_s[c] = x_s[c] @ W[c]
+# runs on the limb-decomposed path below, with the same adaptive limb
+# collapse as the dense public kernel (L = 1..4 from the bounded
+# encoding).  Shared-weight depthwise products launch no kernel: they are
+# a direct tap multiply-accumulate (`core.linear._grouped_conv_parts`).
 #
 # The grid is (slot, channel, M/bm): the channel axis replaces the dense
 # kernels' N/bn axis, M carries the 128-tiling, and the tiny K/mult axes
 # stay whole inside a block (K = kh·kw ≤ 25 — padding them to MXU tiles
 # would waste >5× the FLOPs the grouping saves).  Interpret-mode correct
 # everywhere, like every kernel in this package.
-
-
-class GroupedWeightLimbs(typing.NamedTuple):
-    """Cached per-channel weight-share operands for the grouped RSS kernel.
-
-    Mirrors `rss_matmul.WeightLimbs` with a leading channel axis: ``ws``
-    holds w_i, ``wf`` the fused operand w_i + w_{i+1}, and ``wl``/``wfl``
-    their int8 limbs, from the depthwise kernel reshaped to
-    (3, C, kh·kw, mult)."""
-
-    ws: jax.Array   # (3, C, K, N) uint32 — w_i per channel
-    wf: jax.Array   # (3, C, K, N) uint32 — fused operand w_i + w_{i+1}
-    wl: jax.Array   # (3, 4, C, K, N) int8 — limbs of ws
-    wfl: jax.Array  # (3, 4, C, K, N) int8 — limbs of wf
-
-    @property
-    def channels(self) -> int:
-        return self.ws.shape[1]
-
-    @property
-    def k(self) -> int:
-        return self.ws.shape[2]
-
-    @property
-    def n(self) -> int:
-        return self.ws.shape[3]
-
-
-def grouped_weight_limbs(w_shares: jax.Array) -> GroupedWeightLimbs:
-    """Decompose a (3, C, K, N) grouped weight-share stack once, at setup."""
-    ws = w_shares
-    wf = ws + jnp.roll(ws, -1, axis=0)
-    lim = lambda a: balanced_limbs(a).transpose(1, 0, 2, 3, 4)
-    return GroupedWeightLimbs(ws=ws, wf=wf, wl=lim(ws), wfl=lim(wf))
 
 
 class PublicGroupedLimbs(typing.NamedTuple):
@@ -327,115 +278,6 @@ def public_grouped_limbs(w_enc: jax.Array,
     wl = balanced_limbs(jnp.asarray(w_enc, jnp.uint32))[:n_limbs]
     return PublicGroupedLimbs(w=jnp.asarray(w_enc, jnp.uint32), wl=wl,
                               n_limbs=n_limbs)
-
-
-def _make_grouped_shared_kernel():
-    """Grouped shared-weight kernel body: one (slot, channel, m) block.
-
-    x_ref / xn_ref : (1, 4, 1, bm, K) int8 — limbs of x_p[c] / x_{p+1}[c]
-    wf_ref / w_ref : (1, 4, 1, K, N) int8  — limbs of (w_p+w_{p+1})[c] / w_p[c]
-    o_ref          : (1, 1, bm, N) uint32  — additive product z_p[c]
-    """
-
-    def kernel(x_ref, xn_ref, wf_ref, w_ref, o_ref):
-        acc = jnp.zeros(o_ref.shape[2:], jnp.uint32)
-        for p in range(N_LIMBS):
-            for q in range(N_LIMBS - p):  # p+q > 3 vanishes mod 2^32
-                prod = jax.lax.dot_general(
-                    x_ref[0, p, 0], wf_ref[0, q, 0], (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32)
-                prod += jax.lax.dot_general(
-                    xn_ref[0, p, 0], w_ref[0, q, 0], (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32)
-                acc = acc + (prod.astype(jnp.uint32) << (8 * (p + q)))
-        o_ref[...] = acc[None, None]
-
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "interpret"))
-def _grouped_shared_call(xl, xnl, wl, wfl, *, bm, interpret):
-    """xl/xnl: (S,4,C,Mp,K) int8; wl/wfl: (S,4,C,K,N) int8
-    -> (S,C,Mp,N) uint32.  The whole K axis lives inside one block (no K
-    grid: depthwise contractions are shallow), so no cross-step
-    accumulation is needed."""
-    s, _, c, m, k = xl.shape
-    n = wl.shape[4]
-    bm = min(bm, m)
-    assert m % bm == 0, (m, bm)
-    grid = (s, c, m // bm)
-    return pl.pallas_call(
-        _make_grouped_shared_kernel(),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, N_LIMBS, 1, bm, k),
-                         lambda p, ch, i: (p, 0, ch, i, 0)),
-            pl.BlockSpec((1, N_LIMBS, 1, bm, k),
-                         lambda p, ch, i: (p, 0, ch, i, 0)),
-            pl.BlockSpec((1, N_LIMBS, 1, k, n),
-                         lambda p, ch, i: (p, 0, ch, 0, 0)),
-            pl.BlockSpec((1, N_LIMBS, 1, k, n),
-                         lambda p, ch, i: (p, 0, ch, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bm, n), lambda p, ch, i: (p, ch, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((s, c, m, n), jnp.uint32),
-        interpret=interpret,
-    )(xl, xnl, wfl, wl)
-
-
-def grouped_rss_matmul_ref(x_stack: jax.Array, weights: GroupedWeightLimbs,
-                           x_next_stack: jax.Array | None = None) -> jax.Array:
-    """Reference (exact, same mod-2^32 integers): per-channel uint32
-    batched dots on the cached fused operand."""
-    xn = (jnp.roll(x_stack, -1, axis=0) if x_next_stack is None
-          else x_next_stack)
-
-    def dot(a, b):
-        # (C, M, K) @ (C, K, N) -> (C, M, N), channel as the batch dim
-        return jax.lax.dot_general(
-            a, b, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.uint32)
-
-    return jnp.stack([dot(x_stack[i], weights.wf[i])
-                      + dot(xn[i], weights.ws[i])
-                      for i in range(x_stack.shape[0])])
-
-
-def grouped_rss_matmul_parts(x_stack: jax.Array, weights: GroupedWeightLimbs,
-                             *, x_next_stack: jax.Array | None = None,
-                             bm: int = 128, min_dim: int = 8,
-                             interpret: bool | None = None,
-                             cfg: KernelConfig | None = None) -> jax.Array:
-    """All parties' additive grouped products, one kernel launch.
-
-    x_stack: (S, C, M, K) uint32 per-channel activation shares (S = 3
-    stacked sim / 1 per-party).  Returns (S, C, M, N) uint32 with
-    z_i[c] = x_i[c]·(w_i[c]+w_{i+1}[c]) + x_{i+1}[c]·w_i[c] — the grouped
-    fused-operand Alg-2 identity, bit-exact mod 2^32.  Shapes below the
-    tiling threshold fall back to the batched-dot reference (identical
-    integers).  An autotuned ``cfg`` overrides ``bm`` (the only searched
-    block axis here — K stays whole in-block) or forces the reference."""
-    s, c, m, k = x_stack.shape
-    assert (c, k) == (weights.channels, weights.k), \
-        (x_stack.shape, weights.ws.shape)
-    if cfg is not None:
-        if cfg.lowering == LOWERING_REF:
-            return grouped_rss_matmul_ref(x_stack, weights, x_next_stack)
-        bm = cfg.bm
-    if m < min_dim:
-        return grouped_rss_matmul_ref(x_stack, weights, x_next_stack)
-    xp = _pad_axis(x_stack, _TILE, 2)
-    lim = lambda a: balanced_limbs(a).transpose(1, 0, 2, 3, 4)
-    if x_next_stack is None:
-        xl = lim(xp)
-        xnl = jnp.roll(xl, -1, axis=0)
-    else:
-        both = jnp.concatenate([xp, _pad_axis(x_next_stack, _TILE, 2)], 0)
-        bl = lim(both)
-        xl, xnl = bl[:s], bl[s:]
-    out = _grouped_shared_call(xl, xnl, weights.wl, weights.wfl, bm=bm,
-                               interpret=resolve_interpret(interpret))
-    return out[:, :, :m, :]
 
 
 def _make_grouped_public_kernel(n_w_limbs: int):
@@ -495,9 +337,8 @@ def bin_grouped_matmul_ref(x_stack: jax.Array,
 
 
 def bin_grouped_matmul_parts(x_stack: jax.Array, weights: PublicGroupedLimbs,
-                             *, bm: int = 128, min_dim: int = 8,
-                             interpret: bool | None = None,
-                             cfg: KernelConfig | None = None) -> jax.Array:
+                             *, bm: int = _BM, min_dim: int = 8,
+                             interpret: bool | None = None) -> jax.Array:
     """Every held slot's local grouped product with a public depthwise
     kernel: z_s[c] = x_s[c] @ W[c] mod 2^32 — zero communication, and the
     public limb collapse cuts the per-cell dots to Σ_{q<L}(4−q) like the
@@ -505,10 +346,6 @@ def bin_grouped_matmul_parts(x_stack: jax.Array, weights: PublicGroupedLimbs,
     s, c, m, k = x_stack.shape
     assert (c, k) == (weights.channels, weights.k), \
         (x_stack.shape, weights.w.shape)
-    if cfg is not None:
-        if cfg.lowering == LOWERING_REF:
-            return bin_grouped_matmul_ref(x_stack, weights)
-        bm = cfg.bm
     if m < min_dim:
         return bin_grouped_matmul_ref(x_stack, weights)
     xp = _pad_axis(x_stack, _TILE, 2)

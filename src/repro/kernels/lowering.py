@@ -1,32 +1,17 @@
-"""Kernel lowering policy shared by the RSS matmul families.
+"""Platform-aware interpret default shared by the RSS matmul families.
 
-Two concerns live here so that ``rss_matmul`` / ``bin_rss_matmul`` and the
-autotuner (`kernels/autotune.py`) can agree on them without an import cycle:
-
-* **Platform-aware interpret default.**  The Pallas kernels historically
-  hardcoded ``interpret=True`` (the only mode that runs on CPU).  On a TPU
-  backend the compiled lowering is both available and the entire point, so
-  the default is now resolved per platform: compiled where Mosaic supports
-  it, interpreter fallback everywhere else.  Passing an explicit bool still
-  wins — the bit-identity tests exercise both lowerings explicitly.
-
-* **``KernelConfig``** — the unit the autotuner searches over and
-  ``compile_secure`` attaches to ops (``op["kcfg"]``).  It is a NamedTuple of
-  static leaves (ints + a lowering string) on purpose: when
-  ``make_secure_infer_mesh`` re-flattens the op tree to shard arrays over the
-  party mesh, these leaves are non-arrays and ride through as static
-  structure, so a tuned config survives the shard_map rebuild untouched.
+The Pallas kernels run compiled (Mosaic) on a TPU backend and through the
+Pallas interpreter everywhere else, which is bit-identical but slow.  An
+explicit bool still wins: the bit-identity tests exercise both lowerings.
+Kernel vs jnp reference is chosen elsewhere, once, by
+``compile_secure(use_kernel_dot=...)``.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import Optional
 
 import jax
-
-# Lowering names understood by the dispatchers in rss_matmul/bin_rss_matmul.
-LOWERING_KERNEL = "kernel"  # Pallas launch (compiled or interpret per platform)
-LOWERING_REF = "ref"        # jnp/XLA reference path (dot_general over limbs)
 
 # Backends whose Pallas lowering we trust for these int8-limb kernels.
 _COMPILED_BACKENDS = ("tpu",)
@@ -36,36 +21,10 @@ def default_interpret() -> bool:
     """True when the current backend needs the Pallas interpreter.
 
     TPU backends run the compiled Mosaic lowering; CPU (and any other host
-    platform) falls back to ``interpret=True``, which is bit-identical but
-    slow — the autotuner exists precisely to route such platforms to the
-    reference lowering instead.
-    """
+    platform) falls back to ``interpret=True``."""
     return jax.default_backend() not in _COMPILED_BACKENDS
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
     """Resolve an ``interpret`` argument: None means platform default."""
     return default_interpret() if interpret is None else bool(interpret)
-
-
-class KernelConfig(NamedTuple):
-    """One point in the autotuner's search space.
-
-    ``bm/bn/bk`` are Pallas block sizes (``bk`` is ignored by the grouped
-    family, which keeps K whole in-block).  ``lowering`` selects the Pallas
-    kernel vs. the XLA reference path.  All fields are static pytree leaves.
-    """
-
-    bm: int = 128
-    bn: int = 128
-    bk: int = 128
-    lowering: str = LOWERING_KERNEL
-
-    def describe(self) -> str:
-        if self.lowering == LOWERING_REF:
-            return "ref"
-        return f"kernel bm={self.bm} bn={self.bn} bk={self.bk}"
-
-
-# The fixed default every call site used before autotuning existed.
-DEFAULT_CONFIG = KernelConfig()

@@ -14,7 +14,6 @@ from .bin_rss_matmul import (PublicGroupedLimbs, PublicWeightLimbs,
                              bin_grouped_matmul_parts, bin_rss_matmul_parts)
 from .binary_matmul import binary_binary_matmul, binary_weight_matmul
 from .flash_attention import flash_attention
-from .lowering import KernelConfig
 from .ring_matmul import ring_matmul
 from .rss_matmul import WeightLimbs, precompute_weight_limbs, rss_matmul_parts
 
@@ -91,8 +90,7 @@ def rss_matmul_dot(a: jax.Array, b: jax.Array) -> jax.Array:
 
 
 def bin_rss_matmul_op(x_stack: jax.Array,
-                      weights: PublicWeightLimbs,
-                      cfg: KernelConfig | None = None) -> jax.Array:
+                      weights: PublicWeightLimbs) -> jax.Array:
     """Local share-stack product with a PUBLIC weight matrix (binary-domain
     engine, DESIGN.md §11): z_s = x_s @ W for every share slot the caller
     holds — no communication, no neighbour operand, and the public limb
@@ -103,7 +101,7 @@ def bin_rss_matmul_op(x_stack: jax.Array,
     s = x_stack.shape[0]
     lead = x_stack.shape[1:-1]
     x2 = x_stack.reshape(s, -1, x_stack.shape[-1])
-    out = bin_rss_matmul_parts(x2, weights, cfg=cfg)
+    out = bin_rss_matmul_parts(x2, weights)
     return out.reshape((s,) + lead + (weights.n,))
 
 
@@ -121,20 +119,18 @@ def _unfold_grouped(out: jax.Array, lead, n: int):
 
 
 def bin_grouped_matmul_op(x_stack: jax.Array,
-                          weights: PublicGroupedLimbs,
-                          cfg: KernelConfig | None = None) -> jax.Array:
+                          weights: PublicGroupedLimbs) -> jax.Array:
     """Local per-channel product with a PUBLIC depthwise kernel (bin-public
     path): z_s[c] = x_s[c] @ W[c] for every held slot — zero communication,
     adaptive public limb collapse.  x_stack: (S, ..., K, C) patch stack;
     returns (S, ..., C, N)."""
     lead = x_stack.shape[1:-2]
-    out = bin_grouped_matmul_parts(_fold_grouped(x_stack), weights, cfg=cfg)
+    out = bin_grouped_matmul_parts(_fold_grouped(x_stack), weights)
     return _unfold_grouped(out, lead, weights.n)
 
 
 def rss_matmul_parts_op(x_stack: jax.Array, x_next_stack: jax.Array,
-                        weights: WeightLimbs,
-                        cfg: KernelConfig | None = None) -> jax.Array:
+                        weights: WeightLimbs) -> jax.Array:
     """Full 3-party additive-product stack from one fused kernel launch.
 
     x_stack / x_next_stack: (S, ..., K) uint32 share stacks in additive
@@ -154,5 +150,5 @@ def rss_matmul_parts_op(x_stack: jax.Array, x_next_stack: jax.Array,
     else:
         w_own = WeightLimbs(*(t.own_view(a) for a in weights))
         xn2 = x_next_stack.reshape(s, -1, x_next_stack.shape[-1])
-    out = rss_matmul_parts(x2, w_own, x_next_stack=xn2, cfg=cfg)
+    out = rss_matmul_parts(x2, w_own, x_next_stack=xn2)
     return out.reshape((s,) + lead + (weights.n,))

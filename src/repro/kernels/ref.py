@@ -25,6 +25,26 @@ def binary_binary_matmul_ref(a: jax.Array, w: jax.Array) -> jax.Array:
                                preferred_element_type=jnp.int32)
 
 
+def grouped_rss_matmul_ref(x_stack: jax.Array, ws: jax.Array, wf: jax.Array,
+                           x_next_stack: jax.Array | None = None) -> jax.Array:
+    """All parties' additive grouped (depthwise) products mod 2^32:
+    z_i[c] = x_i[c]·wf_i[c] + x_{i+1}[c]·ws_i[c], the fused-operand Alg-2
+    identity per channel, with wf_i = w_i + w_{i+1}.  x_stack: (S, C, M, K)
+    uint32 shares; ws/wf: (S, C, K, N); ``x_next_stack`` defaults to the
+    party-axis roll of x_stack.  Returns (S, C, M, N) uint32."""
+    xn = (jnp.roll(x_stack, -1, axis=0) if x_next_stack is None
+          else x_next_stack)
+
+    def dot(a, b):
+        # (C, M, K) @ (C, K, N) -> (C, M, N), channel as the batch dim
+        return jax.lax.dot_general(
+            a, b, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.uint32)
+
+    return jnp.stack([dot(x_stack[i], wf[i]) + dot(xn[i], ws[i])
+                      for i in range(x_stack.shape[0])])
+
+
 def flash_attention_ref(q, k, v, causal: bool = True):
     """q: (B,S,H,hd), k/v: (B,S,Hkv,hd) — plain softmax attention (GQA)."""
     b, s, h, hd = q.shape

@@ -51,13 +51,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .limbs import N_LIMBS, balanced_limbs
-from .lowering import KernelConfig, LOWERING_REF, resolve_interpret
+from .lowering import resolve_interpret
 
 __all__ = ["WeightLimbs", "precompute_weight_limbs", "rss_matmul",
            "rss_matmul_parts", "rss_matmul_parts_ref"]
 
 PARTIES = 3
 _TILE = 128
+# Block shape (bm, bn, bk) of every launch: one MXU tile on each axis.
+# No shape has been tuned on the chip yet.
+_BM = _BN = _BK = _TILE
 
 
 class WeightLimbs(typing.NamedTuple):
@@ -179,8 +182,8 @@ def _rss_matmul_call(xl, xnl, wl, wfl, *, bm, bn, bk, interpret):
 
 
 def rss_matmul(x_stack: jax.Array, weights: WeightLimbs, *,
-               x_next_stack: jax.Array | None = None, bm: int = 128,
-               bn: int = 128, bk: int = 128,
+               x_next_stack: jax.Array | None = None, bm: int = _BM,
+               bn: int = _BN, bk: int = _BK,
                interpret: bool | None = None) -> jax.Array:
     """All parties' additive products in one kernel launch.
 
@@ -229,19 +232,12 @@ def rss_matmul_parts_ref(x_stack: jax.Array, weights: WeightLimbs,
 
 def rss_matmul_parts(x_stack: jax.Array, weights: WeightLimbs, *,
                      x_next_stack: jax.Array | None = None,
-                     min_dim: int = 8, interpret: bool | None = None,
-                     cfg: KernelConfig | None = None) -> jax.Array:
+                     min_dim: int = 8,
+                     interpret: bool | None = None) -> jax.Array:
     """Kernel dispatch with the small-shape fallback used across kernels/:
-    both paths are exact mod 2^32, so results are bit-identical.
-
-    ``cfg`` (an autotuned `KernelConfig`) overrides the fixed defaults:
-    ``lowering="ref"`` forces the XLA reference path, otherwise its block
-    sizes replace the 128-cube default."""
+    both paths are exact mod 2^32, so results are bit-identical."""
     _, m, k = x_stack.shape
-    if cfg is not None and cfg.lowering == LOWERING_REF:
-        return rss_matmul_parts_ref(x_stack, weights, x_next_stack)
     if min(m, k, weights.n) < min_dim:
         return rss_matmul_parts_ref(x_stack, weights, x_next_stack)
-    bm, bn, bk = (cfg.bm, cfg.bn, cfg.bk) if cfg is not None else (128, 128, 128)
     return rss_matmul(x_stack, weights, x_next_stack=x_next_stack,
-                      bm=bm, bn=bn, bk=bk, interpret=interpret)
+                      interpret=interpret)

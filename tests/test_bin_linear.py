@@ -16,12 +16,10 @@ from repro.kernels.bin_rss_matmul import (bin_grouped_matmul_parts,
                                           bin_grouped_matmul_ref,
                                           bin_rss_matmul_parts,
                                           bin_rss_matmul_ref,
-                                          grouped_rss_matmul_parts,
-                                          grouped_rss_matmul_ref,
-                                          grouped_weight_limbs,
                                           min_public_limbs,
                                           public_grouped_limbs,
                                           public_weight_limbs)
+from repro.kernels.ref import grouped_rss_matmul_ref
 from repro.nn import bnn
 from test_secure_model import _grid_input, _random_net_params
 
@@ -100,44 +98,39 @@ def test_public_limb_collapse():
 
 
 # ---------------------------------------------------------------------------
-# Grouped (depthwise) kernels — the sepconv half of the §13 pipeline
+# Grouped (depthwise) products — the sepconv half of the §13 pipeline
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("c,m,k,n", [
-    (16, 196, 25, 1),    # MnistNet3-sep shape (5×5 depthwise, mult 1)
-    (4, 128, 9, 1),      # 3×3 depthwise
-    (3, 33, 9, 2),       # non-tile-aligned M, channel multiplier > 1
+@pytest.mark.parametrize("c,k,mult,hw,stride,padding", [
+    (16, 5, 1, 14, 1, 2),   # MnistNet3-sep shape (5×5 depthwise)
+    (4, 3, 1, 8, 1, 1),     # 3×3 depthwise
+    (3, 3, 2, 11, 2, 0),    # channel multiplier > 1, strided
+    (3, 3, 1, 32, 1, 1),    # CifarNet2's first layer
 ])
-def test_grouped_shared_kernel_exact(c, m, k, n):
-    """Grouped shared-weight kernel == per-channel batched-dot reference ==
-    RSS identity, bit-exact mod 2^32 — the fused-operand Alg-2 per
-    channel."""
-    key = jax.random.PRNGKey(c + 7 * m + 13 * k)
-    xs = jax.random.bits(key, (3, c, m, k), jnp.uint32)
-    ws = jax.random.bits(jax.random.fold_in(key, 1), (3, c, k, n), jnp.uint32)
-    wl = grouped_weight_limbs(ws)
-    got = np.asarray(grouped_rss_matmul_parts(xs, wl, min_dim=1))
-    ref = np.asarray(grouped_rss_matmul_ref(xs, wl))
-    assert np.array_equal(got, ref)
-    # Σ_s z_s[c] == (Σ x_s)[c] @ (Σ w_s)[c] mod 2^32 per channel
-    tot = (got[0] + got[1] + got[2]).astype(np.uint32)
-    want = np.asarray(jax.lax.dot_general(
-        xs.sum(0), ws.sum(0), (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.uint32))
-    assert np.array_equal(tot, want)
-
-
-def test_grouped_kernel_pair_stack():
-    """Explicit x_next (the MeshTransport layout, own+next passed
-    separately) is bit-identical to the stacked-sim roll."""
-    key = jax.random.PRNGKey(5)
-    xs = jax.random.bits(key, (3, 4, 128, 9), jnp.uint32)
-    wl = grouped_weight_limbs(
-        jax.random.bits(jax.random.fold_in(key, 1), (3, 4, 9, 1), jnp.uint32))
-    implicit = np.asarray(grouped_rss_matmul_parts(xs, wl))
-    explicit = np.asarray(grouped_rss_matmul_parts(
-        xs, wl, x_next_stack=jnp.roll(xs, -1, axis=0)))
-    assert np.array_equal(implicit, explicit)
+def test_tap_product_reconstructs_depthwise_conv(c, k, mult, hw, stride,
+                                                 padding):
+    """The served shared-weight depthwise product: Σ_s z_s of
+    `_grouped_conv_parts` is the plain depthwise convolution of Σx and Σw
+    mod 2^32, on shares drawn from the whole ring."""
+    key = jax.random.PRNGKey(c + 7 * k + 13 * hw)
+    b = 2
+    x = RSS(jax.random.bits(key, (3, b, hw, hw, c), jnp.uint32), RING32)
+    w = RSS(jax.random.bits(jax.random.fold_in(key, 1),
+                            (3, k, k, 1, c * mult), jnp.uint32), RING32)
+    z = np.asarray(linear._grouped_conv_parts(x, w, stride, padding, c))
+    got = z.sum(0, dtype=np.uint32)
+    xp = np.pad(np.asarray(x.shares).sum(0, dtype=np.uint32),
+                ((0, 0), (padding, padding), (padding, padding), (0, 0)))
+    wp = np.asarray(w.shares).sum(0, dtype=np.uint32)[:, :, 0]
+    ho = (hw + 2 * padding - k) // stride + 1
+    want = np.zeros((b, ho, ho, c * mult), np.uint32)
+    for i in range(k):
+        for j in range(k):
+            tap = xp[:, i:i + stride * ho:stride, j:j + stride * ho:stride]
+            # output channel o reads input channel o // mult
+            want += np.repeat(tap, mult, axis=-1) * wp[i, j]
+    assert got.shape == want.shape
+    assert np.array_equal(got, want)
 
 
 @pytest.mark.parametrize("wmag", [1, 3000, 300000, None])  # L = 1/2/3/4
@@ -209,9 +202,9 @@ def _im2col_grouped_parts(x, w, stride, padding, groups):
         return a.reshape(a.shape[0], -1, kh * kw, cin).transpose(0, 3, 1, 2)
     wst = t.own_view(w.shares).reshape(-1, kh * kw, cin, mult)
     wn = t.next_view(w.shares).reshape(-1, kh * kw, cin, mult)
-    wl = grouped_weight_limbs(wst.transpose(0, 2, 1, 3))
-    wl = wl._replace(wf=(wst + wn).transpose(0, 2, 1, 3))
-    z = grouped_rss_matmul_ref(fold(t.own_view(cols.shares)), wl,
+    z = grouped_rss_matmul_ref(fold(t.own_view(cols.shares)),
+                               wst.transpose(0, 2, 1, 3),
+                               (wst + wn).transpose(0, 2, 1, 3),
                                fold(t.next_view(cols.shares)))
     return z.transpose(0, 2, 1, 3).reshape(z.shape[0], b, ho, wo, cout)
 

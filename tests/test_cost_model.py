@@ -6,7 +6,7 @@ batch / fusing state — the closed-form table and the protocol stack can
 never drift apart silently.  On top of that, the solver's assignments
 must reproduce the legacy §11 path labels (ties keep the historical
 preference order), the per-op ``engine`` override must actually steer
-the executor, and an autotuned ``kcfg`` must never change values.
+the executor, and no file outside the repo may steer a build.
 """
 import json
 
@@ -172,39 +172,45 @@ def test_sepconv_kernel_requests_by_weight_mode():
             and [r for r in public if r[0] == "bin_rss_matmul"])
 
 
-def test_kcfg_from_cache_is_bit_identical(tmp_path):
-    """A compile that pins autotuned configs (here: forced ref lowering via
-    a hand-written cache) must produce bit-identical logits — tuning is
-    schedule, never math."""
-    from repro.kernels import autotune
-
+def test_build_reads_no_kernel_cache(tmp_path, monkeypatch):
+    """A build reads no kernel-schedule file: with ``HOME`` and
+    ``REPRO_AUTOTUNE_CACHE`` both leading to an old autotune cache that
+    would force every launch onto the jnp lowering, the kernel-path model
+    carries no ``kcfg`` on any op and answers bit for bit as a build made
+    without them."""
     net, batch = "MnistNet1", 8
+    shape = (batch,) + INPUT_SHAPES[net]
     params = init_bnn(jax.random.PRNGKey(0), net)
-    plain = compile_secure(params, net, jax.random.PRNGKey(1), RING32)
-    reqs = cost_model.model_cost(
-        plain, (batch,) + INPUT_SHAPES[net]).kernel_requests()
-    cache = tmp_path / "autotune.json"
-    entries = {autotune.cache_key(f, m, k, n, n_limbs=l, channels=c):
+    dep = cost_model.LAN.with_batch(batch)
+
+    def build():
+        return compile_secure(params, net, jax.random.PRNGKey(1), RING32,
+                              use_kernel_dot=True, deployment=dep)
+
+    clean = build()
+    reqs = cost_model.model_cost(clean, shape).kernel_requests()
+    assert reqs
+    tile = lambda d: -(-d // 128) * 128
+    entries = {f"{f}.m{tile(m)}k{tile(k)}n{tile(n)}.L{l}."
+               f"{jax.default_backend()}":
                {"bm": 128, "bn": 128, "bk": 128, "lowering": "ref",
                 "us": 1.0, "default_us": 2.0, "space": "test"}
-               for f, m, k, n, l, c in reqs}
-    cache.write_text(json.dumps({"version": 1, "entries": entries}))
+               for f, m, k, n, l, _ in reqs}
+    cache = json.dumps({"version": 1, "entries": entries})
+    (tmp_path / ".cache" / "repro").mkdir(parents=True)
+    (tmp_path / ".cache" / "repro" / "autotune.json").write_text(cache)
+    (tmp_path / "autotune.json").write_text(cache)
+    monkeypatch.setenv("HOME", str(tmp_path))
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "autotune.json"))
 
-    tuned = compile_secure(params, net, jax.random.PRNGKey(1), RING32,
-                           use_kernel_dot=True,
-                           deployment=cost_model.LAN.with_batch(batch),
-                           autotune_cache=cache)
-    stamped = [c for op in tuned.ops for c in op.get("kcfg", [])
-               if c is not None]
-    assert stamped and all(c.lowering == "ref" for c in stamped)
-
-    x = np.random.default_rng(0).integers(
-        0, 2, (batch,) + INPUT_SHAPES[net]).astype(np.float32) - 0.5
+    planted = build()
+    assert not [op for op in planted.ops if "kcfg" in op]
+    x = np.random.default_rng(0).integers(0, 2, shape).astype(np.float32) \
+        - 0.5
     xs = share(x, jax.random.PRNGKey(3), RING32)
     parties = Parties.setup(jax.random.PRNGKey(7))
-    out_plain = secure_infer(plain, xs, parties)
-    out_tuned = secure_infer(tuned, xs, parties)
-    assert np.array_equal(np.asarray(out_plain), np.asarray(out_tuned))
+    assert np.array_equal(np.asarray(secure_infer(clean, xs, parties)),
+                          np.asarray(secure_infer(planted, xs, parties)))
 
 
 # ---------------------------------------------------------------------------

@@ -10,10 +10,16 @@ from repro.core import linear
 from repro.core.linear import set_fused_rounds
 from repro.core.secure_model import (compile_secure, secure_infer,
                                      secure_infer_cost)
+from repro.kernels.bin_rss_matmul import (bin_grouped_matmul_parts,
+                                          bin_grouped_matmul_ref,
+                                          bin_rss_matmul, bin_rss_matmul_ref,
+                                          public_grouped_limbs,
+                                          public_weight_limbs)
 from repro.kernels.limbs import count_decompositions
+from repro.kernels.lowering import resolve_interpret
 from repro.kernels.ops import rss_matmul_dot
-from repro.kernels.rss_matmul import (precompute_weight_limbs, rss_matmul_parts,
-                                      rss_matmul_parts_ref)
+from repro.kernels.rss_matmul import (precompute_weight_limbs, rss_matmul,
+                                      rss_matmul_parts, rss_matmul_parts_ref)
 from test_secure_model import _grid_input, _random_net_params
 
 
@@ -35,6 +41,49 @@ def test_rss_matmul_kernel_exact(m, k, n):
         xs.sum(0), ws.sum(0), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.uint32))
     assert np.array_equal(tot, want)
+
+
+_BLOCKS = [(128, 128, 128), (256, 128, 128), (128, 256, 128),
+           (128, 128, 256)]
+
+
+@pytest.mark.parametrize(
+    "family,bm,bn,bk",
+    [("rss_matmul",) + b for b in _BLOCKS]
+    + [("bin_rss_matmul",) + b for b in _BLOCKS]
+    + [("bin_grouped_matmul", bm, None, None) for bm in (128, 256)])
+def test_kernel_exact_at_block_shape(family, bm, bn, bk):
+    """Each kernel gives the jnp reference's integers at block shapes
+    other than its fixed one: one block or two along each axis of 256³
+    operands (the grouped kernel: along M, 4 channels of 3×3 taps)."""
+    key = jax.random.PRNGKey(bm + 3 * (bn or 0) + 7 * (bk or 0))
+    kx, kw = jax.random.split(key)
+    if family == "bin_grouped_matmul":
+        xs = jax.random.bits(kx, (3, 4, 256, 9), jnp.uint32)
+        w = public_grouped_limbs(jax.random.bits(kw, (4, 9, 1), jnp.uint32))
+        got = bin_grouped_matmul_parts(xs, w, bm=bm)
+        want = bin_grouped_matmul_ref(xs, w)
+    else:
+        xs = jax.random.bits(kx, (3, 256, 256), jnp.uint32)
+        wbits = jax.random.bits(kw, (3, 256, 256), jnp.uint32)
+        if family == "rss_matmul":
+            w = precompute_weight_limbs(wbits)
+            got = rss_matmul(xs, w, bm=bm, bn=bn, bk=bk)
+            want = rss_matmul_parts_ref(xs, w)
+        else:
+            w = public_weight_limbs(wbits[0])
+            got = bin_rss_matmul(xs, w, bm=bm, bn=bn, bk=bk)
+            want = bin_rss_matmul_ref(xs, w)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_resolve_interpret_platform_default():
+    """Interpret-vs-compiled defaults are platform-aware — compiled on
+    TPU, interpret elsewhere; explicit wins always."""
+    on_tpu = jax.default_backend() == "tpu"
+    assert resolve_interpret(None) == (not on_tpu)
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
 
 
 def test_shared_limb_decomposition_counts(parties):

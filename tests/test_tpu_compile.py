@@ -1,4 +1,4 @@
-"""Compile-only checks of the four RSS kernel families for a TPU v5e chip.
+"""Compile-only checks of the three RSS kernel families for a TPU v5e chip.
 
 Each test compiles one kernel dispatcher (limb decomposition, padding and
 the Pallas launch) with ``interpret=False`` at a launch shape of secure
@@ -16,12 +16,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.bin_rss_matmul import (GroupedWeightLimbs,
-                                          PublicGroupedLimbs,
+from repro.kernels.bin_rss_matmul import (PublicGroupedLimbs,
                                           PublicWeightLimbs,
                                           bin_grouped_matmul_parts,
-                                          bin_rss_matmul_parts,
-                                          grouped_rss_matmul_parts)
+                                          bin_rss_matmul_parts)
 from repro.kernels.rss_matmul import WeightLimbs, rss_matmul_parts
 
 V5E_HBM_BYTES = 16 * 10**9
@@ -83,8 +81,8 @@ DENSE_6 = [(32768, 27, 64), (32768, 576, 64), (8192, 576, 128),
            (512, 2304, 512), (512, 4608, 512), (128, 4608, 512),
            (32, 512, 512), (32, 512, 10)]
 # (M, C): the depthwise convolutions (K = 3x3 taps, N = 1); with shared
-# weights they launch no kernel, and the grouped shared kernel is compiled
-# at these shapes as library code
+# weights they launch no kernel (direct tap products), with public ones
+# the grouped public-limb kernel
 GROUPED = [(32768, 3), (32768, 16), (8192, 16), (8192, 32), (2048, 32),
            (2048, 48)]
 
@@ -106,17 +104,6 @@ def test_bin_rss_matmul_compiles(one_chip, m, k, n):
         [_spec((S, m, k), jnp.uint32, one_chip),
          _spec((k, n), jnp.uint32, one_chip),
          _spec((L_PUBLIC, _tile(k), _tile(n)), jnp.int8, one_chip)])
-
-
-@pytest.mark.parametrize("m,c", GROUPED)
-def test_grouped_rss_matmul_compiles(one_chip, m, c):
-    k, n = 9, 1
-    u32 = [_spec((S, c, k, n), jnp.uint32, one_chip)] * 2
-    i8 = [_spec((S, 4, c, k, n), jnp.int8, one_chip)] * 2
-    _compile_and_check(
-        lambda x, *w: grouped_rss_matmul_parts(x, GroupedWeightLimbs(*w),
-                                               interpret=False),
-        [_spec((S, c, m, k), jnp.uint32, one_chip), *u32, *i8])
 
 
 @pytest.mark.parametrize("m,c", GROUPED)
@@ -154,8 +141,10 @@ def test_cifarnet2_inline_runner_launches(one_chip, monkeypatch):
                      _spec((S,) + shape, RING32.dtype, one_chip)
                      ).compile().as_text()
     assert reqs and {r[0] for r in reqs} == {"rss_matmul"}
-    assert "_grouped_shared_call" not in text
     assert text.count(KERNEL_CALL) == len(reqs)
+    assert [ln for ln in text.splitlines() if KERNEL_CALL in ln] == \
+        [ln for ln in text.splitlines()
+         if KERNEL_CALL in ln and "%_rss_matmul_call." in ln]
 
 
 def test_cifarnet6_inline_runner_launches(one_chip, monkeypatch):
@@ -225,15 +214,15 @@ def _dense_call(one_chip):
 
 def _grouped_call(one_chip):
     (m, c), k, n = GROUPED[-1], 9, 1
-    return (lambda x, *w: grouped_rss_matmul_parts(
-                x, GroupedWeightLimbs(*w), interpret=False),
+    return (lambda x, w, wl: bin_grouped_matmul_parts(
+                x, PublicGroupedLimbs(w, wl, L_PUBLIC), interpret=False),
             [_spec((S, c, m, k), jnp.uint32, one_chip),
-             *[_spec((S, c, k, n), jnp.uint32, one_chip)] * 2,
-             *[_spec((S, 4, c, k, n), jnp.int8, one_chip)] * 2])
+             _spec((c, k, n), jnp.uint32, one_chip),
+             _spec((L_PUBLIC, c, k, n), jnp.int8, one_chip)])
 
 
 @pytest.mark.parametrize("kernel,call", [("_rss_matmul_call", _dense_call),
-                                         ("_grouped_shared_call",
+                                         ("_grouped_public_call",
                                           _grouped_call)])
 def test_kernel_keeps_its_name_inside_a_ledger_scope(one_chip, kernel, call):
     """Inside the executor's scopes (``comm.scope``) a kernel launch keeps
