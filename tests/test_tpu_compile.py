@@ -142,9 +142,8 @@ def test_cifarnet2_inline_runner_launches(one_chip, monkeypatch):
                      ).compile().as_text()
     assert reqs and {r[0] for r in reqs} == {"rss_matmul"}
     assert text.count(KERNEL_CALL) == len(reqs)
-    assert [ln for ln in text.splitlines() if KERNEL_CALL in ln] == \
-        [ln for ln in text.splitlines()
-         if KERNEL_CALL in ln and "%_rss_matmul_call." in ln]
+    assert all("%_rss_matmul_call." in ln
+               for ln in text.splitlines() if KERNEL_CALL in ln)
 
 
 def test_cifarnet6_inline_runner_launches(one_chip, monkeypatch):
